@@ -8,6 +8,10 @@ q.K -> online-softmax -> .V pass per tile with the running (m, l, acc) state
 in VMEM scratch.  All G query heads of a GQA group ride along with their
 shared KV tile, so GQA directly multiplies arithmetic intensity by G.
 
+The wrapper lays K/V out heads-first, ``(B, KV, T, hd)``, and the query as
+``(B, KV, G, hd)``, so every block's last two dims are ``(rows, hd)`` — the
+(sublane, lane) tile Mosaic requires.
+
 Per-sequence lengths are prefetched to SMEM (scalar memory) and drive the
 masking; fully-masked tail blocks cost one VPU pass but no MXU work.
 """
@@ -27,11 +31,11 @@ NEG_INF = -1e30
 def _decode_kernel(
     len_ref,      # SMEM (B,) int32 lengths
     q_ref,        # (1, 1, G, hd): this kv-head's query group
-    k_ref,        # (1, bt, 1, hd)
-    v_ref,        # (1, bt, 1, hd)
+    k_ref,        # (1, 1, bt, hd)
+    v_ref,        # (1, 1, bt, hd)
     o_ref,        # (1, 1, G, hd)
-    m_ref,        # scratch (G,)
-    l_ref,        # scratch (G,)
+    m_ref,        # scratch (G, 1)
+    l_ref,        # scratch (G, 1)
     acc_ref,      # scratch (G, hd)
     *,
     scale: float,
@@ -48,19 +52,19 @@ def _decode_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                   # (G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)             # (bt, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                   # (bt, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (G, bt)
     t_pos = it * block_t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(t_pos <= len_ref[b], s, NEG_INF)
 
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
         p, v, preferred_element_type=jnp.float32
     )
     m_ref[...] = m_new
@@ -68,7 +72,7 @@ def _decode_kernel(
     @pl.when(it == n_t_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -92,8 +96,9 @@ def decode_attention(
     assert T % block_t == 0, (T, block_t)
     n_t = T // block_t
 
-    # regroup q so each kv-head's G query heads are contiguous: (B, 1, KV*G, hd)
-    qg = q.reshape(B, 1, KV, G, hd).reshape(B, 1, KV * G, hd)
+    # each kv-head's G query heads are contiguous: (B, KV, G, hd)
+    qg = q.reshape(B, KV, G, hd)
+    kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
     grid = (B, KV, n_t)
     kernel = functools.partial(
@@ -105,18 +110,18 @@ def decode_attention(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, G, hd), lambda b, h, it, lens: (b, 0, h, 0)),
-                pl.BlockSpec((1, block_t, 1, hd), lambda b, h, it, lens: (b, it, h, 0)),
-                pl.BlockSpec((1, block_t, 1, hd), lambda b, h, it, lens: (b, it, h, 0)),
+                pl.BlockSpec((1, 1, G, hd), lambda b, h, it, lens: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, block_t, hd), lambda b, h, it, lens: (b, h, it, 0)),
+                pl.BlockSpec((1, 1, block_t, hd), lambda b, h, it, lens: (b, h, it, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, it, lens: (b, 0, h, 0)),
+            out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, it, lens: (b, h, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
                 pltpu.VMEM((G, hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, 1, KV * G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), qg, k, v)
+    )(lengths.astype(jnp.int32), qg, kt, vt)
     return out.reshape(B, H, hd)

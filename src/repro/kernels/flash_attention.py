@@ -1,6 +1,8 @@
 """Blockwise online-softmax attention (prefill hot loop) as a Pallas kernel.
 
-TPU mapping: the grid streams (batch, q-head, q-block, kv-block) tiles
+TPU mapping: the wrapper lays the heads out in front, ``(B, H, S, hd)``, so
+every block's last two dims are ``(block, hd)`` — the (sublane, lane) tile
+Mosaic requires.  The grid streams (batch, q-head, q-block, kv-block) tiles
 through VMEM; the innermost kv axis iterates sequentially per q-block, so the
 running max / sum / accumulator live in VMEM scratch across kv steps —
 Pallas double-buffers the HBM->VMEM block fetches automatically, overlapping
@@ -26,12 +28,12 @@ NEG_INF = -1e30
 
 
 def _flash_kernel(
-    q_ref,        # (1, bq, 1, hd)
-    k_ref,        # (1, bk, 1, hd)
-    v_ref,        # (1, bk, 1, hd)
-    o_ref,        # (1, bq, 1, hd)
-    m_ref,        # scratch (bq,)
-    l_ref,        # scratch (bq,)
+    q_ref,        # (1, 1, bq, hd)
+    k_ref,        # (1, 1, bk, hd)
+    v_ref,        # (1, 1, bk, hd)
+    o_ref,        # (1, 1, bq, hd)
+    m_ref,        # scratch (bq, 1)
+    l_ref,        # scratch (bq, 1)
     acc_ref,      # scratch (bq, hd)
     *,
     scale: float,
@@ -50,9 +52,9 @@ def _flash_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)            # (bq, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bk, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)                  # (bq, hd)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (bk, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (bq, bk)
     if causal:
@@ -65,11 +67,11 @@ def _flash_kernel(
         s = jnp.where(k_pos <= q_pos, s, NEG_INF)
 
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
         p, v, preferred_element_type=jnp.float32
     )
     m_ref[...] = m_new
@@ -77,7 +79,7 @@ def _flash_kernel(
     @pl.when(ik == n_kv_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)               # fully-masked rows -> 0
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -105,27 +107,30 @@ def flash_attention(
     assert Sq % block_q == 0 and Sk % block_k == 0, (Sq, block_q, Sk, block_k)
     n_q, n_k = Sq // block_q, Sk // block_k
 
+    # heads in front: each block's trailing dims are (block, hd)
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     grid = (B, H, n_q, n_k)
     kernel = functools.partial(
         _flash_kernel,
         scale=float(scale), causal=causal, q_offset=int(q_offset),
         block_q=block_q, block_k=block_k, n_kv_blocks=n_k,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd), lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[
             # VMEM scratch carrying the online-softmax state across kv steps
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(qt, kt, vt)
+    return out.transpose(0, 2, 1, 3)

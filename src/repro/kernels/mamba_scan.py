@@ -7,9 +7,13 @@ tiles the *channel* axis (d_inner) over the grid and VPU lanes, and streams
 
   grid = (batch, d_blocks, n_chunks)   # chunk axis innermost => sequential
 
-Within a chunk the kernel runs the recurrence with a ``fori_loop`` over the
-chunk's timesteps, fully vectorized over the (block_d, d_state) tile — on
-TPU each step is one fused multiply-add on the VPU while the next chunk's
+The state is held as ``(d_state, block_d)``: channels on lanes, state on
+sublanes.  Within a chunk the kernel runs the recurrence with a
+``fori_loop`` over the chunk's timesteps; each step reads its rows of
+(x, dt) from an f32 copy of the chunk with ``pl.ds``, takes its column of
+B and C by a one-hot lane reduction (Mosaic has no dynamic lane slice),
+and writes its output row through a ref — on TPU each step is a few fused
+VPU passes over the (d_state, block_d) tile while the next chunk's
 (x, dt, B, C) tiles are being DMA'd in.  The f32 state never leaves VMEM
 between chunks (this is exactly the XDT principle at register level: the
 carried state stays producer-resident; only the streamed inputs move).
@@ -28,14 +32,17 @@ from jax.experimental.pallas import tpu as pltpu
 def _scan_kernel(
     x_ref,        # (1, chunk, bd)
     dt_ref,       # (1, chunk, bd)
-    b_ref,        # (1, chunk, ds)
-    c_ref,        # (1, chunk, ds)
-    a_ref,        # (bd, ds)
-    d_ref,        # (bd,)
-    h0_ref,       # (1, bd, ds)
+    b_ref,        # (1, ds, chunk)  B transposed: state on sublanes
+    c_ref,        # (1, ds, chunk)
+    a_ref,        # (ds, bd)        A transposed
+    d_ref,        # (1, bd)
+    h0_ref,       # (1, ds, bd)
     y_ref,        # out (1, chunk, bd)
-    h_out_ref,    # out (1, bd, ds)
-    h_ref,        # scratch (bd, ds) f32: carried state
+    h_out_ref,    # out (1, ds, bd)
+    h_ref,        # scratch (ds, bd) f32: carried state
+    x_scr,        # scratch (chunk, bd) f32
+    dt_scr,       # scratch (chunk, bd) f32
+    y_scr,        # scratch (chunk, bd) f32
     *,
     chunk: int,
     n_chunks: int,
@@ -46,26 +53,30 @@ def _scan_kernel(
     def _init():
         h_ref[...] = h0_ref[0].astype(jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)                      # (chunk, bd)
-    dt = dt_ref[0].astype(jnp.float32)
-    B_in = b_ref[0].astype(jnp.float32)                   # (chunk, ds)
+    x_scr[...] = x_ref[0].astype(jnp.float32)
+    dt_scr[...] = dt_ref[0].astype(jnp.float32)
+    B_in = b_ref[0].astype(jnp.float32)                   # (ds, chunk)
     C_in = c_ref[0].astype(jnp.float32)
-    A = a_ref[...].astype(jnp.float32)                    # (bd, ds)
-    D = d_ref[...].astype(jnp.float32)                    # (bd,)
+    A = a_ref[...].astype(jnp.float32)                    # (ds, bd)
+    lane = jax.lax.broadcasted_iota(jnp.int32, B_in.shape, 1)
 
-    def step(t, carry):
-        h, y = carry
-        a_t = jnp.exp(dt[t][:, None] * A)                 # (bd, ds)
-        b_t = (dt[t] * x[t])[:, None] * B_in[t][None, :]  # (bd, ds)
+    def column(m, t):                                     # (ds, chunk) -> (ds, 1)
+        return jnp.sum(jnp.where(lane == t, m, 0.0), axis=1, keepdims=True)
+
+    def step(t, h):
+        x_t = x_scr[pl.ds(t, 1), :]                       # (1, bd)
+        dt_t = dt_scr[pl.ds(t, 1), :]
+        a_t = jnp.exp(dt_t * A)                           # (ds, bd)
+        b_t = (dt_t * x_t) * column(B_in, t)              # (ds, bd)
         h = a_t * h + b_t
-        y_t = jnp.sum(h * C_in[t][None, :], axis=-1)      # (bd,)
-        return h, jax.lax.dynamic_update_index_in_dim(y, y_t, t, 0)
+        y_scr[pl.ds(t, 1), :] = jnp.sum(
+            h * column(C_in, t), axis=0, keepdims=True
+        )                                                 # (1, bd)
+        return h
 
-    h, y = jax.lax.fori_loop(
-        0, chunk, step, (h_ref[...], jnp.zeros((chunk, x.shape[1]), jnp.float32))
-    )
-    h_ref[...] = h
-    y_ref[0] = (y + x * D[None, :]).astype(y_ref.dtype)
+    h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
+    D = d_ref[...].astype(jnp.float32)                    # (1, bd)
+    y_ref[0] = (y_scr[...] + x_scr[...] * D).astype(y_ref.dtype)
 
     @pl.when(ic == n_chunks - 1)
     def _finalize():
@@ -104,21 +115,29 @@ def mamba_scan(
         in_specs=[
             pl.BlockSpec((1, chunk, block_d), lambda b, id_, ic: (b, ic, id_)),
             pl.BlockSpec((1, chunk, block_d), lambda b, id_, ic: (b, ic, id_)),
-            pl.BlockSpec((1, chunk, ds), lambda b, id_, ic: (b, ic, 0)),
-            pl.BlockSpec((1, chunk, ds), lambda b, id_, ic: (b, ic, 0)),
-            pl.BlockSpec((block_d, ds), lambda b, id_, ic: (id_, 0)),
-            pl.BlockSpec((block_d,), lambda b, id_, ic: (id_,)),
-            pl.BlockSpec((1, block_d, ds), lambda b, id_, ic: (b, id_, 0)),
+            pl.BlockSpec((1, ds, chunk), lambda b, id_, ic: (b, 0, ic)),
+            pl.BlockSpec((1, ds, chunk), lambda b, id_, ic: (b, 0, ic)),
+            pl.BlockSpec((ds, block_d), lambda b, id_, ic: (0, id_)),
+            pl.BlockSpec((1, block_d), lambda b, id_, ic: (0, id_)),
+            pl.BlockSpec((1, ds, block_d), lambda b, id_, ic: (b, 0, id_)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, block_d), lambda b, id_, ic: (b, ic, id_)),
-            pl.BlockSpec((1, block_d, ds), lambda b, id_, ic: (b, id_, 0)),
+            pl.BlockSpec((1, ds, block_d), lambda b, id_, ic: (b, 0, id_)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bsz, S, d_in), x.dtype),
-            jax.ShapeDtypeStruct((Bsz, d_in, ds), jnp.float32),
+            jax.ShapeDtypeStruct((Bsz, ds, d_in), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_d, ds), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((ds, block_d), jnp.float32),
+            pltpu.VMEM((chunk, block_d), jnp.float32),
+            pltpu.VMEM((chunk, block_d), jnp.float32),
+            pltpu.VMEM((chunk, block_d), jnp.float32),
+        ],
         interpret=interpret,
-    )(x, dt, B_in, C_in, A, D, h0)
-    return y, h_last
+    )(
+        x, dt, B_in.swapaxes(1, 2), C_in.swapaxes(1, 2), A.T,
+        D.reshape(1, d_in), h0.astype(jnp.float32).swapaxes(1, 2),
+    )
+    return y, h_last.swapaxes(1, 2)

@@ -15,14 +15,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 names explicit/auto axis types; older versions have neither
-    from jax.sharding import AxisType
-
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}  # noqa: E731
-except ImportError:
-    _AXIS_KW = lambda n: {}  # noqa: E731
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -37,7 +30,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"need {ndev} devices for the production mesh, have {len(devices)} "
             "(the dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512)"
         )
-    return jax.make_mesh(shape, axes, devices=devices, **_AXIS_KW(len(axes)))
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: Optional[int] = None) -> Mesh:
@@ -47,4 +41,5 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: Optional[int] = None) -> 
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n], **_AXIS_KW(len(axes)))
+    return jax.make_mesh(shape, axes, devices=jax.devices()[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))

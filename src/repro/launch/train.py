@@ -27,7 +27,7 @@ def main():
     ap.add_argument("--model", type=int, default=1)
     ap.add_argument("--pod", type=int, default=0)
     ap.add_argument("--devices", type=int, default=0,
-                    help="force host device count (set BEFORE jax import)")
+                    help="force this many CPU host devices (set BEFORE jax import)")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--workdir", default="/tmp/repro_train")
     ap.add_argument("--lr", type=float, default=3e-4)
@@ -40,6 +40,8 @@ def main():
     args = ap.parse_args()
 
     if args.devices:
+        # forced host devices live on the CPU backend, never on a chip
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", "")
@@ -53,8 +55,10 @@ def main():
     from ..models import init_params
     from ..optim import OptConfig
     from ..train import Trainer, TrainerConfig
+    from .compile_cache import enable_compile_cache
     from .mesh import make_host_mesh
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.loss_chunk:
         cfg = dataclasses.replace(cfg, loss_chunk=args.loss_chunk)

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from ..distributed.sharding import ShardingRules
 from .config import ModelConfig
 

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from .config import ModelConfig, MoEConfig
 
 

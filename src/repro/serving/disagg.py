@@ -50,7 +50,7 @@ from ..core.dag import Edge, Stage, WorkflowDAG
 from ..core.refs import XDTRef
 from ..core.scheduler import ScalingPolicy
 from ..core.transfer import TransferEngine, modeled_transfer_seconds
-from ..core.workflow import WorkflowEngine
+from ..core.workflow import WorkflowEngine, WorkflowRequest
 from ..models.config import ModelConfig
 from .engine import Request, ServingEngine
 
@@ -109,6 +109,8 @@ class DisaggregatedServer:
         self.pod_of_request: Dict[int, int] = {}
         self.instance_of_request: Dict[int, int] = {}
         self.handoffs = 0
+        #: request id -> workflow request of each handoff still in flight
+        self.workflow_requests: Dict[int, WorkflowRequest] = {}
         # -- the handoff workflow: a DAG bound onto the event-driven engine.
         # Custom handlers move the REAL cache through self.transfer; the
         # engine contributes steering, queueing, autoscaling accounting, and
@@ -220,9 +222,21 @@ class DisaggregatedServer:
         """
         req = Request(next(self.prefill_pod._ids), np.asarray(prompt, np.int32),
                       max_new_tokens)
-        self.engine.submit(self.binding.entry, req)
+        self.workflow_requests[req.request_id] = self.engine.submit(
+            self.binding.entry, req
+        )
         self.engine.sim.run()
+        self._raise_failed()
         return req.request_id
+
+    def _raise_failed(self) -> None:
+        """A handoff whose handler raised (a prefill OOM, a compile error, a
+        failed pull) ends as a failed workflow request: raise its error, once."""
+        for rid, wreq in list(self.workflow_requests.items()):
+            if wreq.status in ("ok", "error", "failed"):
+                del self.workflow_requests[rid]
+                if wreq.error is not None:
+                    raise wreq.error
 
     def step(self) -> None:
         for pod in self.decode_pods:
@@ -253,6 +267,7 @@ class DisaggregatedServer:
                 break
             self.step()
             steps += 1
+        self._raise_failed()
         for pod in self.decode_pods:
             done.update(pod.completed)
         return done

@@ -14,6 +14,7 @@ are exact, not approximate).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -57,6 +58,16 @@ def insert_cache(batch_cache: PyTree, single_cache: PyTree, slot: int) -> PyTree
     return jax.tree.map(ins, batch_cache, single_cache)
 
 
+@functools.lru_cache(maxsize=None)
+def _step_fns(cfg: ModelConfig, mesh, max_len: int):
+    """Jitted (prefill, decode) shared by every engine with the same config,
+    mesh and context budget: the pods of one server compile each shape once."""
+    return (
+        jax.jit(make_prefill_fn(cfg, mesh, remat="none", pad_to=max_len)),
+        jax.jit(make_decode_fn(cfg, mesh)),
+    )
+
+
 class ServingEngine:
     """Single-pod continuous batching."""
 
@@ -74,8 +85,7 @@ class ServingEngine:
         self.mesh = mesh
         self.max_batch = max_batch
         self.max_len = max_len
-        self.prefill = jax.jit(make_prefill_fn(cfg, mesh, remat="none", pad_to=max_len))
-        self.decode = jax.jit(make_decode_fn(cfg, mesh))
+        self.prefill, self.decode = _step_fns(cfg, mesh, max_len)
         self.cache = empty_cache(cfg, max_batch, max_len)
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.last_tokens = jnp.zeros((max_batch, 1), jnp.int32)

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import smoke_config
@@ -237,6 +237,21 @@ def check_grad_accum_equivalence():
     }
 
 
+def check_chip_smoke_phase_c():
+    """chip_smoke.py's four-chip phase (cross-device pulls onto devices 1-3,
+    collective patterns on a 4-device mesh) at toy sizes."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+    chip_smoke.phase_c(jax.devices()[:4], payload_bytes=1 << 20,
+                       object_bytes=64 << 10)
+    return {"ok": True}
+
+
 CHECKS = {
     "patterns": check_patterns,
     "sharded_train": check_sharded_train_matches_single,
@@ -245,6 +260,7 @@ CHECKS = {
     "compressed_psum": check_compressed_psum,
     "elastic_checkpoint": check_elastic_checkpoint,
     "grad_accum": check_grad_accum_equivalence,
+    "chip_smoke_phase_c": check_chip_smoke_phase_c,
 }
 
 

@@ -31,6 +31,8 @@ def multidevice_results():
     """Run the 8-device check battery once; tests assert on its JSON."""
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    # the child forces host devices: it must never reach for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests", "_multidevice_checks.py")],
         capture_output=True, text=True, env=env, timeout=900,

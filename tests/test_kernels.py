@@ -2,7 +2,8 @@
 
 All kernels run in interpret mode on CPU (the kernel BODY executes, so the
 blocking/indexing/accumulator logic is what's validated; the TPU lowering
-shares that body).
+shares that body).  Whether Mosaic accepts the blocking at real widths is
+checked by the ahead-of-time compiles in tests/test_tpu_compile.py.
 """
 import jax
 import jax.numpy as jnp
@@ -265,9 +266,35 @@ def test_xdt_pull_roundtrip_quantized_cache():
 def test_ops_fallback_on_ragged_shapes():
     """Non-divisible shapes route to the oracle, same numerics contract."""
     ks = jax.random.split(jax.random.PRNGKey(11), 3)
-    q = _rand(ks[0], (1, 100, 3, 24), jnp.float32)     # 100 % 128 != 0
-    k = _rand(ks[1], (1, 100, 3, 24), jnp.float32)
-    v = _rand(ks[2], (1, 100, 3, 24), jnp.float32)
+    q = _rand(ks[0], (1, 200, 3, 24), jnp.float32)     # 200 % 128 != 0
+    k = _rand(ks[1], (1, 200, 3, 24), jnp.float32)
+    v = _rand(ks[2], (1, 200, 3, 24), jnp.float32)
+    before = ops.FALLBACKS["flash_attention"]
     out = ops.flash_attention(q, k, v, causal=True)
     want = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-5, atol=1e-5)
+    assert ops.FALLBACKS["flash_attention"] == before + 1   # the fallback is visible
+
+
+def test_ops_aligned_shapes_take_the_kernel():
+    """Tileable shapes run the kernel (interpreted on CPU): no fallback.
+    640 columns exceed one 512-wide block, so the pull tiles them by 128."""
+    before = dict(ops.FALLBACKS)
+    src = _rand(jax.random.PRNGKey(12), (1024, 640), jnp.float32)
+    out = ops.xdt_pull(src, out_dtype=jnp.bfloat16)
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32),
+        np.asarray(ref.xdt_pull_ref(src, out_dtype=jnp.bfloat16), np.float32))
+    assert dict(ops.FALLBACKS) == before
+    assert ops.kernel_mode() == "interpret"
+
+
+def test_ops_refuses_backends_without_a_kernel_path(monkeypatch):
+    """Only TPU (Mosaic) and CPU (interpret) have a kernel path; any other
+    backend raises instead of silently interpreting."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    src = jnp.ones((512, 128), jnp.float32)
+    with pytest.raises(RuntimeError, match="no Pallas kernel path"):
+        ops.xdt_pull(src)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.kernel_mode() == "mosaic"

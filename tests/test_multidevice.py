@@ -40,3 +40,9 @@ def test_elastic_checkpoint_reshape(multidevice_results):
 def test_grad_accum_equivalence(multidevice_results):
     """Microbatched accumulation reproduces the single-shot step."""
     _assert_ok(multidevice_results, "grad_accum")
+
+
+def test_chip_smoke_four_chip_phase(multidevice_results):
+    """chip_smoke.py --chips 4's phase: each pull lands on its own device and
+    the patterns match numpy (rehearsed on 4 of the host devices)."""
+    _assert_ok(multidevice_results, "chip_smoke_phase_c")

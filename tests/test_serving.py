@@ -120,3 +120,38 @@ def test_disagg_ssm_arch():
     done = srv.run_until_drained()
     assert done[rid].generated == _greedy_reference(cfg, params, prompt, 4,
                                                     max_len=24)
+
+
+def _failing_prefill(*_args, **_kw):
+    raise RuntimeError("prefill died")
+
+
+def test_disagg_surfaces_a_failed_prefill(setup, monkeypatch):
+    """A handler error ends the handoff's workflow request as failed; the
+    server raises it instead of reporting a request that never ran."""
+    cfg, params = setup
+    srv = DisaggregatedServer(cfg, params, n_decode_pods=1, max_batch=2,
+                              max_len=32, backend="xdt")
+    monkeypatch.setattr(srv.prefill_pod, "prefill_request", _failing_prefill)
+    with pytest.raises(RuntimeError, match="prefill died"):
+        srv.submit(np.arange(1, 5), max_new_tokens=3)
+    assert srv.handoffs == 0
+
+
+@pytest.mark.parametrize("fail_prefill,rc", [(False, 0), (True, 1)])
+def test_serve_launcher_exit_code(fail_prefill, rc, monkeypatch, tmp_path, capsys):
+    """``launch.serve --disagg`` exits 0 when every request completes and
+    non-zero when prefill fails."""
+    from repro.launch import serve
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    if fail_prefill:
+        monkeypatch.setattr(ServingEngine, "prefill_request", _failing_prefill)
+    assert serve.main(["--arch", "smollm_360m", "--smoke", "--disagg",
+                       "--requests", "3", "--new-tokens", "2",
+                       "--prompt-len", "6", "--max-len", "32"]) == rc
+    out, err = capsys.readouterr()
+    if fail_prefill:
+        assert "FAILED" in err and "prefill died" in err
+    else:
+        assert "disagg[xdt]: 3 requests, 3 handoffs" in out
