@@ -53,6 +53,9 @@ Layers
 * :mod:`cluster`   — calibrated discrete-event simulator for the paper's
                      latency/bandwidth/cost evaluation.
 * :mod:`cost`      — AWS cost model (Table 2).
+* :mod:`tracing`   — spans and counters of the engine, the transfer engine
+                     and serving on the host's clock, recorded while a JAX
+                     profiler session records (and written into its trace).
 """
 from .buffers import BufferRegistry, RegistryStats
 from .clock import Clock, MonotonicClock, VirtualClock

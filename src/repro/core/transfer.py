@@ -48,7 +48,6 @@ it directly, and per-medium op counts accumulate in ``media_acct`` so
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, ClassVar, Dict, Optional, Tuple, Type, Union
 
 import jax
@@ -80,10 +79,12 @@ from .refs import (
     XDTRef,
 )
 from .telemetry import TelemetryHub
+from . import tracing
 
 Sharding = Any  # jax.sharding.Sharding
 
 _obj_new = object.__new__
+_tracing = tracing.enabled
 
 
 def _nbytes(x) -> int:
@@ -132,7 +133,6 @@ class TransferStats:
     transfers: int = 0
     bytes_moved: int = 0
     modeled_seconds: float = 0.0
-    wall_seconds: float = 0.0
     #: pulls that took the co-placement shared-memory path (``get(local=True)``
     #: on an instance-resident medium): modeled at memcpy speed, not the NIC
     local_pulls: int = 0
@@ -490,7 +490,6 @@ class TransferEngine:
         service: Optional[ServiceStore] = None,
         clock: Optional[Callable[[], float]] = None,
         telemetry: Union[TelemetryHub, None, bool] = None,
-        wall_timing: bool = False,
     ):
         if backend not in _BACKEND_REGISTRY:
             raise ValueError(
@@ -508,9 +507,6 @@ class TransferEngine:
             net.inline_limit if inline_limit is None else inline_limit
         )
         self.stats = TransferStats()
-        #: wall-clock put/get timing is diagnostic-only and costs two
-        #: ``perf_counter`` calls per op on the hot path; opt in when needed.
-        self._wall_timing = wall_timing
         self.acct = TransferAccounting()
         #: per-medium accounting for through-storage ops, so a mixed-backend
         #: (per-edge routed) run can be priced by each medium's fee structure
@@ -619,7 +615,12 @@ class TransferEngine:
         (per-edge routing): the chosen medium is sealed inside the ref, so
         ``get`` dispatches to the same medium with no side-channel state.
         """
-        if backend is None and self._fast_single_owner and not self._wall_timing:
+        if _tracing() and not tracing.within("xfer.put"):
+            with tracing.span("xfer.put", medium=backend or self.backend,
+                              nbytes=_nbytes(obj)):
+                return self.put(obj, n_retrievals, block=block, timeout=timeout,
+                                backend=backend)
+        if backend is None and self._fast_single_owner:
             nb = getattr(obj, "nbytes", None)
             if nb is not None and n_retrievals >= 1:
                 # fused put: single array -> unlocked registry -> sealed ref,
@@ -667,9 +668,7 @@ class TransferEngine:
                 ref._nonce = nonce.to_bytes(_NONCE_LEN, "big")
                 ref._sealed = None
                 return ref
-        elif (
-            backend is None and self._fast_service and not self._wall_timing
-        ):
+        elif backend is None and self._fast_service:
             nb = getattr(obj, "nbytes", None)
             if nb is not None and n_retrievals >= 1:
                 # fused through-storage put: inlined ServiceStore.put +
@@ -732,12 +731,7 @@ class TransferEngine:
                 return ref
         strat = self._backend if backend is None else self._strategy(backend)
         nbytes = _nbytes(obj)
-        if self._wall_timing:
-            t0 = time.perf_counter()
-            buffer_id, epoch = strat.put(obj, n_retrievals, nbytes, block, timeout)
-            self.stats.wall_seconds += time.perf_counter() - t0
-        else:
-            buffer_id, epoch = strat.put(obj, n_retrievals, nbytes, block, timeout)
+        buffer_id, epoch = strat.put(obj, n_retrievals, nbytes, block, timeout)
         shape, dtype = _describe(obj)
         return self.minter.mint(
             RefPayload(
@@ -769,6 +763,11 @@ class TransferEngine:
         shared-memory speed instead of the NIC path.  Durable service media
         ignore the hint — the storage round-trip is node-independent.
         """
+        if _tracing() and not tracing.within("xfer.get"):
+            payload = self.minter.open(ref)
+            with tracing.span("xfer.get", medium=payload.medium or self.backend,
+                              nbytes=payload.desc.nbytes):
+                return self.get(ref, sharding, local)
         minter = self.minter
         if type(ref) is SealedRef and ref._minter is minter:
             payload = ref._payload     # same-domain fast open (no crypto)
@@ -781,7 +780,6 @@ class TransferEngine:
             and self._fast_single_owner
             and not local
             and sharding is None
-            and not self._wall_timing
         ):
             # fused get: unlocked registry retrieval + cached latency model,
             # no strategy dispatch (mirrors BufferRegistry.get exactly)
@@ -825,7 +823,6 @@ class TransferEngine:
             self._fast_service
             and medium == self.backend
             and sharding is None
-            and not self._wall_timing
         ):
             # fused through-storage get: inlined ServiceStore fetch/consume +
             # accounting + cached latency model — mirrors _ServiceBackend.get
@@ -897,8 +894,7 @@ class TransferEngine:
         local = local and medium in INSTANCE_RESIDENT_MEDIA
         if self._fault_penalty is not None:
             # fault plan installed: give the injector a chance to reclassify
-            # the failure (wall timing is diagnostic-only and moot under
-            # injected faults, so this branch skips it)
+            # the failure
             try:
                 obj = strat.get(payload)
             except XDTError as e:
@@ -906,10 +902,6 @@ class TransferEngine:
                 if repl is not None and repl is not e:
                     raise repl from e
                 raise
-        elif self._wall_timing:
-            t0 = time.perf_counter()
-            obj = strat.get(payload)
-            self.stats.wall_seconds += time.perf_counter() - t0
         else:
             obj = strat.get(payload)
 
@@ -1089,7 +1081,6 @@ class TransferEngine:
         if (
             medium == "xdt"
             and self._fast_single_owner
-            and not self._wall_timing
             and nb is not None
             and n_retrievals >= 1
         ):
@@ -1144,7 +1135,6 @@ class TransferEngine:
         if (
             medium == self.backend
             and self._fast_service
-            and not self._wall_timing
             and nb is not None
             and n_retrievals >= 1
         ):
@@ -1250,7 +1240,6 @@ class TransferEngine:
             medium == "xdt"
             and self._fast_single_owner
             and not local
-            and not self._wall_timing
         ):
             reg = self.registry
             entries = reg._entries
@@ -1312,7 +1301,6 @@ class TransferEngine:
         if (
             self._fast_service
             and medium == self.backend
-            and not self._wall_timing
         ):
             svc = self.service
             objects = svc._objects

@@ -58,6 +58,7 @@ from __future__ import annotations
 
 import dataclasses
 from array import array
+from inspect import isgeneratorfunction
 from types import GeneratorType
 from typing import Any, Callable, Dict, Generator, List, Optional, Sequence, Tuple
 
@@ -75,9 +76,11 @@ from .errors import (
 from .refs import XDTRef
 from .scheduler import ControlPlane, Deployment, ScalingPolicy
 from .topology import as_coord
+from . import tracing
 from .transfer import TransferEngine
 
 _obj_new = object.__new__
+_tracing = tracing.enabled
 
 
 @dataclasses.dataclass(slots=True)
@@ -681,7 +684,7 @@ class _InvocationTask(AsyncResult):
     __slots__ = (
         "eng", "payload", "fn", "svc_time", "invocation_id",
         "deployment", "instance", "ctx", "t0", "phase", "gen", "send",
-        "throw_", "pending",
+        "throw_", "pending", "request",
     )
 
     # phases: what to do when the simulator calls us back
@@ -721,6 +724,8 @@ class _InvocationTask(AsyncResult):
             self.invocation_id = iid
             if presteered is not None:   # batch-submitted: already steered
                 self.instance, wait = presteered
+            elif _tracing():
+                self.instance, wait = self._steer_traced(dep, affinity)
             elif type(dep) is Deployment:
                 # inlined Deployment.steer: one clock read + due-guarded
                 # reap/mature + one pick — bit-identical to dep.steer(),
@@ -792,6 +797,19 @@ class _InvocationTask(AsyncResult):
             self.send = ev.value
             self._drive_loop()
 
+    def _steer_traced(self, dep, affinity):
+        """The steer inside a ``wf.steer`` span (``Deployment.steer`` is the
+        body inlined above); the task keeps the request it is steered under
+        for the spans of its handler."""
+        self.request = tracing.current_request()
+        with tracing.span("wf.steer", function=self.function,
+                          invocation=self.invocation_id):
+            return dep.steer(affinity)
+
+    def _handler_span(self):
+        return tracing.span("wf.handler", request=getattr(self, "request", None),
+                            function=self.function, invocation=self.invocation_id)
+
     def _push_ctrl(self) -> None:
         ctrl = self.eng._ctrl_latency
         if ctrl > 0:
@@ -813,7 +831,11 @@ class _InvocationTask(AsyncResult):
         ctx.attempt = 0
         ctx.instance = self.instance
         try:
-            out = self.fn(ctx, self.payload)
+            if _tracing() and not isgeneratorfunction(self.fn):
+                with self._handler_span():
+                    out = self.fn(ctx, self.payload)
+            else:
+                out = self.fn(ctx, self.payload)
         except BaseException as e:
             self._fail(e)
             return
@@ -837,7 +859,9 @@ class _InvocationTask(AsyncResult):
         gen = self.gen
         while True:
             try:
-                if self.throw_ is not None:
+                if _tracing():
+                    yielded = self._resume_traced(gen)
+                elif self.throw_ is not None:
                     t, self.throw_ = self.throw_, None
                     yielded = gen.throw(t)
                 else:
@@ -875,6 +899,15 @@ class _InvocationTask(AsyncResult):
             except BaseException as e:
                 self._fail(e)
                 return
+
+    def _resume_traced(self, gen):
+        """One resumption of the generator handler, inside a span."""
+        with self._handler_span():
+            if self.throw_ is not None:
+                t, self.throw_ = self.throw_, None
+                return gen.throw(t)
+            s, self.send = self.send, None
+            return gen.send(s)
 
     def _dispatch_yield(self, yielded) -> bool:
         """Act on one value yielded by a generator handler.
@@ -1080,6 +1113,9 @@ class WorkflowEngine:
     # -- orchestrator ------------------------------------------------------------
     def submit(self, entry: str, payload: Any) -> WorkflowRequest:
         """Enqueue one workflow request; drive with ``drain()``/``run()``."""
+        if _tracing() and not tracing.within("wf.request"):
+            with tracing.root("wf.request", self._request_counter + 1, entry=entry):
+                return self.submit(entry, payload)
         if entry not in self.functions:
             raise KeyError(f"unknown function {entry!r}")
         self._request_counter = rid = self._request_counter + 1
@@ -1109,7 +1145,11 @@ class WorkflowEngine:
         # submit time; the entry deployment is untouched in between), so one
         # reap/mature pass serves all of them and the per-arrival picks are
         # bit-identical to sequential submits.
-        steers = self._deployments[entry].steer_batch(len(payloads))
+        if _tracing():
+            with tracing.span("wf.steer", function=entry, batch=len(payloads)):
+                steers = self._deployments[entry].steer_batch(len(payloads))
+        else:
+            steers = self._deployments[entry].steer_batch(len(payloads))
         out = []
         for payload, presteered in zip(payloads, steers):
             self._request_counter = rid = self._request_counter + 1
@@ -1135,6 +1175,9 @@ class WorkflowEngine:
         """Blocking wrapper: submit one request and drive it to completion;
         on XDTProducerGone the orchestrator re-invokes the entry sub-workflow
         with the original arguments, up to ``max_retries`` times."""
+        if _tracing() and not tracing.within("wf.request"):
+            with tracing.root("wf.request", self._request_counter + 1, entry=entry):
+                return self.run(entry, payload)
         req = self.submit(entry, payload)
         self.sim.run()
         if req.error is not None:    # "error" and terminal "failed" alike
@@ -1183,18 +1226,32 @@ class WorkflowEngine:
         are charged to the *caller's* debt (blocking-chain billing, the
         vSwarm semantics the cost model assumes).
         """
+        traced = _tracing()
+        if traced and not tracing.within("wf.invoke"):
+            with tracing.span("wf.invoke", function=fn_name,
+                              invocation=self._invocation_watermark + 1):
+                return self._invoke_inline(fn_name, payload, parent)
         fn = self.functions.get(fn_name)
         if fn is None:
             raise KeyError(f"unknown function {fn_name!r}")
         invocation_id = self._next_invocation_id()
         deployment = self._deployments[fn_name]
-        instance, wait = deployment.steer()
+        if traced:
+            with tracing.span("wf.steer", function=fn_name, invocation=invocation_id):
+                instance, wait = deployment.steer()
+        else:
+            instance, wait = deployment.steer()
         t0 = self.sim.now
         parent._debt += wait + self._ctrl_latency
         ctx = Context(self, fn_name, attempt=0, instance=instance)
         status, code = "ok", None
         try:
-            out = fn(ctx, payload)
+            if traced:
+                with tracing.span("wf.handler", function=fn_name,
+                                  invocation=invocation_id):
+                    out = fn(ctx, payload)
+            else:
+                out = fn(ctx, payload)
             if type(out) is GeneratorType:
                 raise TypeError(
                     f"generator handler {fn_name!r} cannot be invoked inline; "

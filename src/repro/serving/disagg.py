@@ -43,6 +43,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
+from ..core import tracing
 from ..core.buffers import BufferRegistry
 from ..core.clock import ensure_clock
 from ..core.cluster import Event
@@ -103,8 +104,9 @@ class DisaggregatedServer:
         # prefill pod: only needs the prefill fn — reuse an engine shell
         self.prefill_pod = ServingEngine(cfg, params, mesh, max_batch=1, max_len=max_len)
         self.decode_pods: List[ServingEngine] = [
-            ServingEngine(cfg, params, mesh, max_batch=max_batch, max_len=max_len)
-            for _ in range(n_decode_pods)
+            ServingEngine(cfg, params, mesh, max_batch=max_batch, max_len=max_len,
+                          pod=k)
+            for k in range(n_decode_pods)
         ]
         self.pod_of_request: Dict[int, int] = {}
         self.instance_of_request: Dict[int, int] = {}
@@ -150,7 +152,8 @@ class DisaggregatedServer:
     def _prefill_handler(self, ctx, req: Request):
         """Producer stage: compute the cache, mint the ref, invoke decode."""
         # 1. producer computes the ephemeral object
-        cache, first_token = self.prefill_pod.prefill_request(req)
+        with tracing.span("serve.prefill", prompt_tokens=len(req.prompt)):
+            cache, first_token = self.prefill_pod.prefill_request(req)
         # 2. producer buffers it and mints the reference (data stays put)
         ref: XDTRef = self.transfer.put(cache, n_retrievals=1)
         # 3/4. control plane picks the consumer, which pulls and decodes
@@ -182,6 +185,7 @@ class DisaggregatedServer:
         pod_idx = self._pod_for(ctx.instance.instance_id)
         pod = self.decode_pods[pod_idx]
         pulled = self.transfer.get(ref)
+        wait = None
         while True:
             try:
                 slot = pod.slots.index(None)
@@ -190,8 +194,13 @@ class DisaggregatedServer:
                 # every batch slot busy: the handoff queues on this pod
                 # until step() frees one (instead of crashing, as the
                 # pre-engine implementation did)
+                if wait is None:
+                    wait = tracing.begin("serve.slot_wait", pod=pod_idx)
                 yield self._slot_free_event(pod_idx)
-        pod.admit(req, pulled, first_token, slot)
+        if wait is not None:
+            wait.end()
+        with tracing.span("serve.insert", pod=pod_idx, slot=slot):
+            pod.admit(req, pulled, first_token, slot)
         self.pod_of_request[req.request_id] = pod_idx
         self.instance_of_request[req.request_id] = ctx.instance.instance_id
         self.handoffs += 1
@@ -222,11 +231,13 @@ class DisaggregatedServer:
         """
         req = Request(next(self.prefill_pod._ids), np.asarray(prompt, np.int32),
                       max_new_tokens)
-        self.workflow_requests[req.request_id] = self.engine.submit(
-            self.binding.entry, req
-        )
-        self.engine.sim.run()
-        self._raise_failed()
+        with tracing.root("serve.submit", req.request_id,
+                          prompt_tokens=len(req.prompt)):
+            self.workflow_requests[req.request_id] = self.engine.submit(
+                self.binding.entry, req
+            )
+            self.engine.sim.run()
+            self._raise_failed()
         return req.request_id
 
     def _raise_failed(self) -> None:
@@ -239,9 +250,17 @@ class DisaggregatedServer:
                     raise wreq.error
 
     def step(self) -> None:
-        for pod in self.decode_pods:
-            if any(s is not None for s in pod.slots):
-                pod.step()
+        with tracing.span("serve.round"):
+            for pod in self.decode_pods:
+                if any(s is not None for s in pod.slots):
+                    pod.step()
+            with tracing.span("serve.release"):
+                self._release()
+
+    def _release(self) -> None:
+        """Fire the completion events of finished generations and run the
+        engine: completed handoffs release their decode slots, and queued
+        ones admit into the slots just freed."""
         fired = False
         for pod_idx, pod in enumerate(self.decode_pods):
             freed = False
@@ -255,8 +274,6 @@ class DisaggregatedServer:
                 if slot_ev is not None:
                     slot_ev.set()
         if fired:
-            # completed handoffs release their decode slots; queued ones
-            # admit into the slots just freed
             self.engine.sim.run()
 
     def run_until_drained(self, max_steps: int = 10_000) -> Dict[int, Request]:

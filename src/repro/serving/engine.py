@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import tracing
 from ..models import cache_shapes, make_decode_fn, make_prefill_fn
 from ..models.config import ModelConfig
 
@@ -42,6 +43,17 @@ def empty_cache(cfg: ModelConfig, batch: int, max_len: int) -> PyTree:
     for key, (shape, _axes, dtype) in cache_shapes(cfg, batch, max_len).items():
         out[key] = jnp.zeros(shape, dtype)
     return out
+
+
+def host_int(x, *index) -> int:
+    """``int(x[index])``, or ``int(x)``: one device-to-host read.  Every read
+    of the engine goes through here, so that tracing counts it
+    (``host.syncs``) and times it (``host.sync``)."""
+    if tracing.enabled():
+        tracing.count("host.syncs")
+        with tracing.span("host.sync"):
+            return int(x[index] if index else x)
+    return int(x[index] if index else x)
 
 
 def insert_cache(batch_cache: PyTree, single_cache: PyTree, slot: int) -> PyTree:
@@ -78,6 +90,7 @@ class ServingEngine:
         mesh=None,
         max_batch: int = 4,
         max_len: int = 64,
+        pod: int = 0,
     ):
         assert cfg.has_decode, f"{cfg.name} is encoder-only"
         self.cfg = cfg
@@ -85,6 +98,8 @@ class ServingEngine:
         self.mesh = mesh
         self.max_batch = max_batch
         self.max_len = max_len
+        #: this pod's index among its server's, in traces
+        self.pod = pod
         self.prefill, self.decode = _step_fns(cfg, mesh, max_len)
         self.cache = empty_cache(cfg, max_batch, max_len)
         self.slots: List[Optional[Request]] = [None] * max_batch
@@ -105,7 +120,7 @@ class ServingEngine:
         logits, cache = self.prefill(
             self.params, {"tokens": jnp.asarray(req.prompt)[None]}
         )
-        return cache, int(jnp.argmax(logits[0]))
+        return cache, host_int(jnp.argmax(logits[0]))
 
     def admit(self, req: Request, cache: PyTree, first_token: int, slot: int) -> None:
         self.cache = insert_cache(self.cache, cache, slot)
@@ -125,14 +140,17 @@ class ServingEngine:
         self._refill()
         if all(s is None for s in self.slots):
             return
-        logits, self.cache = self.decode(self.params, self.cache, self.last_tokens)
-        next_tokens = jnp.argmax(logits, axis=-1)
-        self.last_tokens = next_tokens[:, None].astype(jnp.int32)
+        if tracing.enabled():
+            live = sum(s is not None for s in self.slots)
+            with tracing.span("serve.decode", pod=self.pod, live=live):
+                next_tokens = self._decode()
+        else:
+            next_tokens = self._decode()
         self.steps += 1
         for slot, req in enumerate(self.slots):
             if req is None:
                 continue
-            req.generated.append(int(next_tokens[slot]))
+            req.generated.append(host_int(next_tokens, slot))
             if (
                 len(req.generated) >= req.max_new_tokens
                 or len(req.prompt) + len(req.generated) >= self.max_len - 1
@@ -140,6 +158,14 @@ class ServingEngine:
                 req.done = True
                 self.completed[req.request_id] = req
                 self.slots[slot] = None
+
+    def _decode(self):
+        """Dispatch one decode step; returns the next token of every slot,
+        on the device."""
+        logits, self.cache = self.decode(self.params, self.cache, self.last_tokens)
+        next_tokens = jnp.argmax(logits, axis=-1)
+        self.last_tokens = next_tokens[:, None].astype(jnp.int32)
+        return next_tokens
 
     def run_until_drained(self, max_steps: int = 10_000) -> Dict[int, Request]:
         while (self.queue or any(s is not None for s in self.slots)) and self.steps < max_steps:
