@@ -36,8 +36,9 @@ Names are stable; they are what the readers of the records look up:
                     its admit (detached: no parent)
 ``serve.round``     one ``DisaggregatedServer.step``
 ``serve.release``   a round's completion events and the simulator run
-``serve.decode``    one pod's decode dispatch and argmax
-``host.sync``       one device-to-host read
+``serve.decode``    one pod's decode dispatch
+``host.sync``       one device-to-host read; inside a round, the round's
+                    one read of every pod's tokens
 ``host.syncs``      (count) one per device-to-host read
 ==================  ==================================================
 """

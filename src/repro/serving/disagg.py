@@ -53,7 +53,7 @@ from ..core.scheduler import ScalingPolicy
 from ..core.transfer import TransferEngine, modeled_transfer_seconds
 from ..core.workflow import WorkflowEngine, WorkflowRequest
 from ..models.config import ModelConfig
-from .engine import Request, ServingEngine
+from .engine import Request, ServingEngine, host_read
 
 PyTree = Any
 
@@ -250,9 +250,16 @@ class DisaggregatedServer:
                     raise wreq.error
 
     def step(self) -> None:
+        """One decode round: dispatch the step of every pod with a live slot,
+        read all their tokens in one device-to-host read, let each pod
+        collect its own, then release finished generations."""
         with tracing.span("serve.round"):
-            for pod in self.decode_pods:
-                if any(s is not None for s in pod.slots):
+            busy = [pod for pod in self.decode_pods
+                    if any(s is not None for s in pod.slots)]
+            on_device = [pod.dispatch() for pod in busy]
+            if busy:
+                for pod, tokens in zip(busy, host_read(on_device)):
+                    pod.host_tokens = tokens
                     pod.step()
             with tracing.span("serve.release"):
                 self._release()

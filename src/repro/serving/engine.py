@@ -45,15 +45,15 @@ def empty_cache(cfg: ModelConfig, batch: int, max_len: int) -> PyTree:
     return out
 
 
-def host_int(x, *index) -> int:
-    """``int(x[index])``, or ``int(x)``: one device-to-host read.  Every read
-    of the engine goes through here, so that tracing counts it
-    (``host.syncs``) and times it (``host.sync``)."""
+def host_read(x):
+    """``jax.device_get(x)``: an array, or a list of arrays, read to the host
+    in one device-to-host read.  Every read of the engine goes through here,
+    so that tracing counts it (``host.syncs``) and times it (``host.sync``)."""
     if tracing.enabled():
         tracing.count("host.syncs")
         with tracing.span("host.sync"):
-            return int(x[index] if index else x)
-    return int(x[index] if index else x)
+            return jax.device_get(x)
+    return jax.device_get(x)
 
 
 def insert_cache(batch_cache: PyTree, single_cache: PyTree, slot: int) -> PyTree:
@@ -73,10 +73,19 @@ def insert_cache(batch_cache: PyTree, single_cache: PyTree, slot: int) -> PyTree
 @functools.lru_cache(maxsize=None)
 def _step_fns(cfg: ModelConfig, mesh, max_len: int):
     """Jitted (prefill, decode) shared by every engine with the same config,
-    mesh and context budget: the pods of one server compile each shape once."""
+    mesh and context budget: the pods of one server compile each shape once.
+    The decode program also takes each slot's greedy token, so that one
+    dispatch steps a pod: ``decode(params, cache, tokens (B, 1)) -> (next
+    tokens (B, 1) int32, cache)``."""
+    step = make_decode_fn(cfg, mesh)
+
+    def decode(params, cache, tokens):
+        logits, cache = step(params, cache, tokens)
+        return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32), cache
+
     return (
         jax.jit(make_prefill_fn(cfg, mesh, remat="none", pad_to=max_len)),
-        jax.jit(make_decode_fn(cfg, mesh)),
+        jax.jit(decode),
     )
 
 
@@ -104,6 +113,9 @@ class ServingEngine:
         self.cache = empty_cache(cfg, max_batch, max_len)
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.last_tokens = jnp.zeros((max_batch, 1), jnp.int32)
+        #: the dispatched step's tokens, read to the host with other pods'
+        #: by a server, until :meth:`step` collects them
+        self.host_tokens: Optional[np.ndarray] = None
         self.queue: List[Request] = []
         self._ids = itertools.count()
         self.completed: Dict[int, Request] = {}
@@ -120,7 +132,7 @@ class ServingEngine:
         logits, cache = self.prefill(
             self.params, {"tokens": jnp.asarray(req.prompt)[None]}
         )
-        return cache, host_int(jnp.argmax(logits[0]))
+        return cache, int(host_read(jnp.argmax(logits[0])))
 
     def admit(self, req: Request, cache: PyTree, first_token: int, slot: int) -> None:
         self.cache = insert_cache(self.cache, cache, slot)
@@ -135,22 +147,33 @@ class ServingEngine:
                 cache, tok = self.prefill_request(req)
                 self.admit(req, cache, tok, slot)
 
-    def step(self) -> None:
-        """One engine iteration: refill free slots, one decode step."""
+    def dispatch(self) -> Optional[jax.Array]:
+        """Refill free slots and dispatch one decode step.  Returns the next
+        token of every slot, (max_batch, 1), still on the device, or None
+        when no slot is live."""
         self._refill()
         if all(s is None for s in self.slots):
-            return
+            return None
         if tracing.enabled():
             live = sum(s is not None for s in self.slots)
             with tracing.span("serve.decode", pod=self.pod, live=live):
-                next_tokens = self._decode()
+                self._decode()
         else:
-            next_tokens = self._decode()
+            self._decode()
         self.steps += 1
+        return self.last_tokens
+
+    def _decode(self) -> None:
+        self.last_tokens, self.cache = self.decode(self.params, self.cache,
+                                                   self.last_tokens)
+
+    def collect(self, tokens: np.ndarray) -> None:
+        """Append each live slot's token of the dispatched step (``tokens``,
+        read to the host) and free the slots of finished requests."""
         for slot, req in enumerate(self.slots):
             if req is None:
                 continue
-            req.generated.append(host_int(next_tokens, slot))
+            req.generated.append(int(tokens[slot, 0]))
             if (
                 len(req.generated) >= req.max_new_tokens
                 or len(req.prompt) + len(req.generated) >= self.max_len - 1
@@ -159,13 +182,19 @@ class ServingEngine:
                 self.completed[req.request_id] = req
                 self.slots[slot] = None
 
-    def _decode(self):
-        """Dispatch one decode step; returns the next token of every slot,
-        on the device."""
-        logits, self.cache = self.decode(self.params, self.cache, self.last_tokens)
-        next_tokens = jnp.argmax(logits, axis=-1)
-        self.last_tokens = next_tokens[:, None].astype(jnp.int32)
-        return next_tokens
+    def step(self) -> None:
+        """One engine iteration: refill free slots, one decode step, one read
+        of every slot's token, and their :meth:`collect`.  A server that
+        reads the tokens of several pods at once dispatches each pod's step,
+        reads them all, and leaves each pod its own in ``host_tokens``; the
+        step then only collects them."""
+        tokens, self.host_tokens = self.host_tokens, None
+        if tokens is None:
+            on_device = self.dispatch()
+            if on_device is None:
+                return
+            tokens = host_read(on_device)
+        self.collect(tokens)
 
     def run_until_drained(self, max_steps: int = 10_000) -> Dict[int, Request]:
         while (self.queue or any(s is not None for s in self.slots)) and self.steps < max_steps:

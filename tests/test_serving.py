@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from repro.configs import smoke_config
+from repro.core import tracing
 from repro.models import init_params, make_decode_fn, make_prefill_fn
 from repro.serving import DisaggregatedServer, ServingEngine
 
@@ -81,6 +82,83 @@ def test_disagg_matches_single_pod(setup):
     rid = srv.submit(prompt, max_new_tokens=6)
     done = srv.run_until_drained()
     assert done[rid].generated == _greedy_reference(cfg, params, prompt, 6)
+
+
+def _held(srv):
+    """request id -> (pod, slot, request) of every busy decode slot."""
+    return {r.request_id: (k, j, r) for k, pod in enumerate(srv.decode_pods)
+            for j, r in enumerate(pod.slots) if r is not None}
+
+
+def test_disagg_pods_finishing_at_different_rounds(setup):
+    """Requests of different lengths on both pods: each round reads every
+    pod's tokens at once, each slot is freed in the round that read its
+    request's last token, a parked request takes it in that round, and the
+    generations are those of one pod and of the sequential reference."""
+    cfg, params = setup
+    srv = DisaggregatedServer(cfg, params, n_decode_pods=2, max_batch=2,
+                              max_len=32, backend="xdt")
+    prompts = [np.arange(1, 5 + i) + i for i in range(5)]
+    news = [3, 7, 5, 9, 4]
+    rids = [srv.submit(p, max_new_tokens=n) for p, n in zip(prompts[:4], news)]
+    assert {srv.pod_of_request[r] for r in rids} == {0, 1}
+    late = srv.submit(prompts[4], max_new_tokens=news[4])   # every slot busy
+    assert late not in srv.pod_of_request
+    rids.append(late)
+    before, freed_rounds, reused = _held(srv), set(), None
+    rounds = 0
+    while before:
+        lengths = {rid: len(r.generated) for rid, (_, _, r) in before.items()}
+        srv.step()
+        rounds += 1
+        after = _held(srv)
+        freed = set()
+        for rid, (k, j, req) in before.items():
+            assert len(req.generated) == lengths[rid] + 1
+            if len(req.generated) == req.max_new_tokens:
+                assert req.done and rid in srv.decode_pods[k].completed
+                assert rid not in after
+                freed.add((k, j))
+            else:
+                assert not req.done and after[rid][:2] == (k, j)
+        for rid in set(after) - set(before):
+            assert rid == late and after[rid][:2] in freed
+            reused = after[rid][:2]
+        if freed:
+            freed_rounds.add(rounds)
+        before = after
+    assert reused is not None and len(freed_rounds) >= 3
+    done = {rid: req for pod in srv.decode_pods
+            for rid, req in pod.completed.items()}
+    eng = ServingEngine(cfg, params, max_batch=2, max_len=32)
+    single = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    alone = eng.run_until_drained()
+    for rid, sid, p, n in zip(rids, single, prompts, news):
+        assert len(done[rid].generated) == n
+        assert done[rid].generated == alone[sid].generated \
+            == _greedy_reference(cfg, params, p, n)
+
+
+def test_engine_step_reads_its_tokens_once(setup, tmp_path):
+    """Under a profiler session a step with live slots makes one
+    device-to-host read, however many slots it steps; one with none, none."""
+    cfg, params = setup
+    eng = ServingEngine(cfg, params, max_batch=3, max_len=32)
+    for i, n in enumerate([3, 6, 4]):
+        eng.submit(np.arange(1, 5) + i, max_new_tokens=n)
+    eng.step()                          # prefills read their first tokens here
+    tracing.clear()
+    live, reads = [], []
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(5):
+            live.append(sum(r is not None for r in eng.slots))
+            t0 = tracing._now()
+            eng.step()
+            reads.append(sum(c.n for c in tracing.records(t0, tracing._now())
+                             if isinstance(c, tracing.Count) and c.name == "host.syncs"))
+    tracing.clear()
+    assert live == [3, 2, 1, 1, 0]
+    assert reads == [1, 1, 1, 1, 0]
 
 
 def test_disagg_placement_spreads_load(setup):

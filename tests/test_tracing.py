@@ -197,17 +197,22 @@ def test_serving_spans_have_their_parents_and_requests(traced):
     assert {s.request for s in recs if s.name in ("serve.round", "serve.decode")} == {None}
 
 
-def test_host_syncs_per_round_equal_the_live_slots_stepped(traced):
+def test_each_round_reads_every_pods_tokens_once_after_their_dispatch(traced):
     recs = traced["serve"]
     rounds = [s for s in _spans(recs) if s.name == "serve.round"]
     assert rounds
     for r in rounds:
         inside = [x for x in recs if r.start <= x.start <= r.end]
-        live = sum(s.attrs["live"] for s in inside
-                   if isinstance(s, tracing.Span) and s.name == "serve.decode")
-        syncs = sum(c.n for c in inside
-                    if isinstance(c, tracing.Count) and c.name == "host.syncs")
-        assert syncs == live > 0
+        decodes = [s for s in _spans(inside) if s.name == "serve.decode"]
+        syncs = [s for s in _spans(inside) if s.name == "host.sync"]
+        counts = sum(c.n for c in inside
+                     if isinstance(c, tracing.Count) and c.name == "host.syncs")
+        assert sum(s.attrs["live"] for s in decodes) > 0
+        assert counts == len(syncs) == 1
+        assert all(d.end <= syncs[0].start for d in decodes)
+    # both pods step in the same round while both hold a request
+    assert max(sum(s.name == "serve.decode" for s in _spans(recs)
+                   if r.start <= s.start <= r.end) for r in rounds) == 2
     # and each prefill reads its first token once
     prefills = [s for s in _spans(recs) if s.name == "serve.prefill"]
     counts = [c for c in recs if isinstance(c, tracing.Count)]
