@@ -38,6 +38,8 @@ _ALIASES = {
     "hubert-xlarge": "hubert_xlarge",
     "llava-next-mistral-7b": "llava_next_mistral_7b",
     "zamba2-1.2b": "zamba2_1p2b",
+    # served, not in the dry-run matrix
+    "granite-4.0-h-micro": "granite_4p0_h_micro",
 }
 
 

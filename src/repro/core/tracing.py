@@ -31,7 +31,9 @@ Names are stable; they are what the readers of the records look up:
 ``xfer.get``        ``TransferEngine.get`` (``medium``, ``nbytes``)
 ``serve.submit``    one disaggregated request, up to its first token
 ``serve.prefill``   the prefill dispatch and its first-token read
+                    (``tokens``, and ``padded``: the length prefilled)
 ``serve.insert``    the admit of a handed-over cache into a decode slot
+                    (``state_bytes`` of SSM and conv states, ``kv_bytes``)
 ``serve.slot_wait`` a handoff parked behind a full decode batch, until
                     its admit (detached: no parent)
 ``serve.round``     one ``DisaggregatedServer.step``
@@ -40,6 +42,8 @@ Names are stable; they are what the readers of the records look up:
 ``host.sync``       one device-to-host read; inside a round, the round's
                     one read of every pod's tokens
 ``host.syncs``      (count) one per device-to-host read
+``prefill.tokens``  (count) a prefilled prompt's real tokens
+``prefill.pad_tokens`` (count) the pad tokens prefilled after them
 ==================  ==================================================
 """
 from __future__ import annotations

@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import List, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -32,13 +32,55 @@ class SSMConfig:
 
 @dataclasses.dataclass(frozen=True)
 class HybridConfig:
-    """Zamba2-style: shared attention block applied every ``attn_every``
-    backbone layers, with one set of shared weights."""
+    """Mamba2 layers with attention among them, in one of two patterns.
+
+    * Zamba2 (``attn_layers`` empty): one *shared* attention+MLP block, one
+      set of weights of the ``shared_*`` widths, applied before each run of
+      ``attn_every`` Mamba2 layers; every one of the ``n_layers`` is Mamba2.
+    * Granite-4.0-H (``attn_layers`` given): the layers at those indices are
+      attention layers with weights of their own (``n_heads`` over
+      ``n_kv_heads``) in place of Mamba2, and every layer of either kind is
+      followed by its own SwiGLU MLP of width ``d_ff``.
+    """
 
     attn_every: int = 6
     shared_d_ff: int = 8192
     shared_n_heads: int = 32
     shared_n_kv_heads: int = 32
+    attn_layers: Tuple[int, ...] = ()
+
+    def n_attn(self, n_layers: int) -> int:
+        """Attention applications: KV caches a sequence holds."""
+        return len(self.attn_layers) if self.attn_layers else n_layers // self.attn_every
+
+    def n_mamba(self, n_layers: int) -> int:
+        """Mamba2 layers: SSM and conv states a sequence holds."""
+        return n_layers - len(self.attn_layers)
+
+    def segments(self, n_layers: int) -> List[Tuple[str, int, int]]:
+        """The layers in order: ``("attn", g, g + 1)`` is attention
+        application ``g``; ``("mamba", a, b)`` the Mamba2 layers ``a`` to
+        ``b - 1`` of the stacked Mamba2 weights and states."""
+        out: List[Tuple[str, int, int]] = []
+        if not self.attn_layers:
+            e = self.attn_every
+            for g in range(n_layers // e):
+                out += [("attn", g, g + 1), ("mamba", g * e, (g + 1) * e)]
+            if n_layers % e:
+                out.append(("mamba", n_layers - n_layers % e, n_layers))
+            return out
+        m = g = 0
+        for i in range(n_layers):
+            if i in self.attn_layers:
+                out.append(("attn", g, g + 1))
+                g += 1
+            else:
+                if out and out[-1][0] == "mamba":
+                    out[-1] = ("mamba", out[-1][1], m + 1)
+                else:
+                    out.append(("mamba", m, m + 1))
+                m += 1
+        return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,7 +95,16 @@ class ModelConfig:
     vocab: int
     head_dim: Optional[int] = None
     qk_norm: bool = False
+    rope: bool = True                   # False: no position embedding (NoPE)
     rope_theta: float = 1e4
+    attn_scale: Optional[float] = None  # softmax scale; None: head_dim ** -0.5
+    # Granite-style multipliers (the hybrid family): the embedding is scaled
+    # by ``embedding_multiplier``, each layer's mixer and MLP outputs by
+    # ``residual_multiplier`` before their residual adds, and the logits are
+    # divided by ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     rms_eps: float = 1e-5
     causal: bool = True
     tie_embeddings: bool = False
@@ -124,13 +175,20 @@ class ModelConfig:
             else:
                 n_h = d_in // s.head_dim
                 per_layer += d * (2 * d_in + 2 * s.d_state + n_h)  # in_proj(z,x,B,C,dt)
-                per_layer += (d_in + 2 * s.d_state) * s.conv_width
-                per_layer += 2 * n_h + d_in             # A, D, norm
+                per_layer += (d_in + 2 * s.d_state) * (s.conv_width + 1)  # conv, bias
+                per_layer += 3 * n_h + d_in             # dt_bias, A, D, norm
                 per_layer += d_in * d
             per_layer += d  # norm
-        n += L * per_layer
-        if self.family == "hybrid":
-            h = self.hybrid
+        h = self.hybrid
+        if self.family == "hybrid" and h.attn_layers:
+            n_attn = h.n_attn(L)
+            mlp = 3 * d * f + d                         # every layer's MLP, its norm
+            attn = (d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
+                    + self.n_heads * hd * d + d)
+            n += (L - n_attn) * (per_layer + mlp) + n_attn * (attn + mlp)
+        else:
+            n += L * per_layer
+        if self.family == "hybrid" and not h.attn_layers:
             shd = self.hd
             shared = (
                 d * h.shared_n_heads * shd

@@ -1,5 +1,6 @@
 """Transformer layer library: RMSNorm, RoPE, GQA attention (3 sharding
-modes), SwiGLU MLP.
+modes; RoPE or none, ``cfg.rope``; softmax scale ``cfg.attn_scale``), SwiGLU
+MLP.
 
 Attention sharding modes (resolved per-arch from mesh divisibility):
 
@@ -197,10 +198,11 @@ def attention_layer(
     B, S, D = x.shape
     causal = cfg.causal if causal is None else causal
     q, k, v = _project_qkv(x, p, cfg)
-    pos = jnp.arange(S) if positions is None else positions
-    cos, sin = rope_angles(pos, cfg.hd, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.rope:
+        pos = jnp.arange(S) if positions is None else positions
+        cos, sin = rope_angles(pos, cfg.hd, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     kv_out = (k, v) if return_kv else None  # pre-expansion layout for cache
 
     if plan.mode == "head":
@@ -217,7 +219,8 @@ def attention_layer(
             v = lax.with_sharding_constraint(
                 v, rules.named(["batch", None, "kv_heads", None], v.shape)
             )
-        out = chunked_attention(q, k, v, causal=causal, chunk=cfg.attn_chunk)
+        out = chunked_attention(q, k, v, causal=causal, chunk=cfg.attn_chunk,
+                                scale=cfg.attn_scale)
     else:
         out = _seq_parallel_attention(q, k, v, cfg, mesh, causal)
 
@@ -234,14 +237,16 @@ def _seq_parallel_attention(q, k, v, cfg: ModelConfig, mesh, causal: bool):
     tp = int(mesh.shape.get("model", 1)) if mesh is not None else 1
     S = q.shape[1]
     if tp == 1 or S % tp != 0 or mesh is None:
-        return chunked_attention(q, k, v, causal=causal, chunk=cfg.attn_chunk)
+        return chunked_attention(q, k, v, causal=causal, chunk=cfg.attn_chunk,
+                                 scale=cfg.attn_scale)
 
     def local(qb, kb, vb):
         # qb: (B_loc, S/tp, H, hd); kb/vb: (B_loc, S, KV, hd)
         rank = lax.axis_index("model")
         s_loc = qb.shape[1]
         return chunked_attention(
-            qb, kb, vb, causal=causal, q_offset=rank * s_loc, chunk=cfg.attn_chunk
+            qb, kb, vb, causal=causal, q_offset=rank * s_loc, chunk=cfg.attn_chunk,
+            scale=cfg.attn_scale,
         )
 
     axes = tuple(mesh.shape.keys())
@@ -278,9 +283,10 @@ def decode_attention_layer(
     B, _, D = x.shape
     T = cache_k.shape[1]
     q, k_new, v_new = _project_qkv(x, p, cfg)
-    cos, sin = rope_angles(seq_positions[:, None], cfg.hd, cfg.rope_theta)  # (B,1,half)
-    q = apply_rope(q, cos, sin)
-    k_new = apply_rope(k_new, cos, sin)
+    if cfg.rope:
+        cos, sin = rope_angles(seq_positions[:, None], cfg.hd, cfg.rope_theta)  # (B,1,half)
+        q = apply_rope(q, cos, sin)
+        k_new = apply_rope(k_new, cos, sin)
 
     if cfg.decode_scatter_update:
         # §Perf hillclimb: a scatter touches only the updated row — with the
@@ -304,7 +310,7 @@ def decode_attention_layer(
     G = cfg.n_heads // KV
     qg = q.reshape(B, KV, G, cfg.hd)  # Sq == 1 squeezed
     scores = jnp.einsum("bkgh,btkh->bkgt", qg, cache_k).astype(jnp.float32)
-    scores *= cfg.hd ** -0.5
+    scores *= cfg.hd ** -0.5 if cfg.attn_scale is None else cfg.attn_scale
     valid = jnp.arange(T)[None, :] <= seq_positions[:, None]  # (B, T)
     scores = jnp.where(valid[:, None, None], scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)

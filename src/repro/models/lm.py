@@ -6,8 +6,9 @@ Design notes
 ------------
 * **Scan over layers.**  All per-layer parameters are stacked with a leading
   ``n_layers`` dim and the forward is a single ``lax.scan`` (hybrid archs:
-  grouped scans around the shared attention block), keeping HLO size — and
-  hence dry-run compile time — O(1) in depth.
+  one scan per run of Mamba2 layers between attention layers,
+  :func:`hybrid_walk`), keeping HLO size — and hence dry-run compile time —
+  O(1) in depth.
 * **Remat.**  The layer body is wrapped in ``jax.checkpoint`` (policy
   selectable) so 4k-sequence training fits HBM at batch 16/device.
 * **Sharding.**  Tensors are annotated through
@@ -18,7 +19,8 @@ Design notes
 * **Caches.**  Decode state is a pytree: attention archs carry
   ``{"k","v"}`` of shape (L, B, T, KV, hd) with T sequence-sharded over the
   model axis (flash-decoding layout); SSM archs carry (conv, ssm) states;
-  hybrids carry both.  The KV cache is THE ephemeral object the XDT serving
+  hybrids carry both: KV for each attention application, states for each
+  Mamba2 layer.  The KV cache is THE ephemeral object the XDT serving
   path hands between prefill and decode pods.
 """
 from __future__ import annotations
@@ -90,6 +92,21 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Any]:
         out["blocks"] = {
             "ssm": _stack(ssm_param_shapes(cfg), L),
             "ln": ((L, D), ("layers", "embed")),
+        }
+    elif cfg.family == "hybrid" and cfg.hybrid.attn_layers:
+        # Granite-4.0-H: Mamba2 and attention layers, each with its own MLP
+        Lm, La = cfg.hybrid.n_mamba(L), cfg.hybrid.n_attn(L)
+        out["blocks"] = {
+            "ssm": _stack(ssm_param_shapes(cfg), Lm),
+            "ln": ((Lm, D), ("layers", "embed")),
+            "mlp": _stack(mlp_param_shapes(cfg), Lm),
+            "ln2": ((Lm, D), ("layers", "embed")),
+        }
+        out["attn_blocks"] = {
+            "attn": _stack(attn_param_shapes(cfg), La),
+            "mlp": _stack(mlp_param_shapes(cfg), La),
+            "ln1": ((La, D), ("layers", "embed")),
+            "ln2": ((La, D), ("layers", "embed")),
         }
     elif cfg.family == "hybrid":
         h = cfg.hybrid
@@ -223,8 +240,14 @@ def _constrain_hidden(x, build: ModelBuild):
     return _constrain(x, build, ["batch", None, None])
 
 
+def _scaled(x, m: float):
+    """``x * m``, and ``x`` itself where ``m`` is 1 (no op in the program)."""
+    return x if m == 1.0 else x * m
+
+
 def _embed(params, tokens, build: ModelBuild):
     x = params["embed"][tokens].astype(build.cfg.compute_dtype)
+    x = _scaled(x, build.cfg.embedding_multiplier)
     return _constrain(x, build, ["batch", None, None])
 
 
@@ -235,6 +258,8 @@ def _logits(params, x, build: ModelBuild):
         logits = jnp.einsum("bsd,vd->bsv", x, head)
     else:
         logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return _constrain(logits, build, ["batch", None, "vocab"])
 
 
@@ -326,14 +351,17 @@ def forward_transformer(params, x, build: ModelBuild, *, positions=None,
     return x, aux, kvs
 
 
-def forward_ssm(params, x, build: ModelBuild, *, states=None, collect_state=False):
-    """ssm backbone.  states: stacked (L, ...) pytree or None."""
+def forward_ssm(params, x, build: ModelBuild, *, states=None, collect_state=False,
+                length=None):
+    """ssm backbone.  states: stacked (L, ...) pytree or None; ``length``
+    (B,): real lengths of a padded prefill (Mamba2 only)."""
     cfg = build.cfg
     block = mamba1_block if cfg.ssm.version == 1 else mamba2_block
+    kw = {} if length is None else {"length": length}
 
     def body(h, layer):
         bp, st = layer
-        out, new_st = block(rms_norm(h, bp["ln"], cfg.rms_eps), bp["ssm"], cfg, st)
+        out, new_st = block(rms_norm(h, bp["ln"], cfg.rms_eps), bp["ssm"], cfg, st, **kw)
         h = _constrain_hidden(h + out, build)
         return h, (new_st if collect_state else None)
 
@@ -344,58 +372,81 @@ def forward_ssm(params, x, build: ModelBuild, *, states=None, collect_state=Fals
     return x, new_states
 
 
-def forward_hybrid(params, x, build: ModelBuild, *, positions=None,
-                   collect_kv=False, states=None, collect_state=False):
-    """zamba2-style: groups of mamba2 layers + one shared attention block."""
+def hybrid_walk(params, x, build: ModelBuild, attention, *, states=None,
+                collect_state=False, length=None, decode=False):
+    """The hybrid family's layers in order (``HybridConfig.segments``): each
+    run of Mamba2 layers is one scan over its layers' indices, and each
+    attention application calls
+    ``attention(normed x, attention weights, application) -> (out, kv)``.
+    Zamba2's shared block is the same weights at every application;
+    Granite's attention layers have their own, and every layer its own MLP.
+
+    A scan reads each layer's weights and state out of the whole stacks and,
+    with ``collect_state``, writes the new state back into the carried
+    stack in place: slicing a span's stack before its scan would copy it
+    (all the Mamba2 weights, 5.5 GB at granite-4.0-h-micro's size).
+
+    Prefill and training (``decode`` False) constrain the hidden layout
+    between blocks and remat the Mamba2 layers as ``build`` says; decode
+    steps the states one token.  Returns (x before the final norm, the kv
+    of each application, the new states stacked over the Mamba2 layers or
+    None)."""
     cfg = build.cfg
-    h = cfg.hybrid
-    L = cfg.n_layers
-    every = h.attn_every
-    n_apps = L // every
-    shared = params["shared"]
+    eps, r = cfg.rms_eps, cfg.residual_multiplier
+    constrain = (lambda h: h) if decode else (lambda h: _constrain_hidden(h, build))
 
-    def mamba_span(x, bp_span, st_span):
-        def body(hc, layer):
-            bp, st = layer
-            out, new_st = mamba2_block(rms_norm(hc, bp["ln"], cfg.rms_eps), bp["ssm"], cfg, st)
-            hc = _constrain_hidden(hc + out, build)
-            return hc, (new_st if collect_state else None)
-        return lax.scan(_maybe_remat(body, build), x, (bp_span, st_span),
-                        unroll=build.cfg.scan_unroll)
+    def take(tree, i):
+        return jax.tree.map(lambda v: lax.dynamic_index_in_dim(v, i, keepdims=False), tree)
 
-    def shared_attn(x):
-        a, kv = attention_layer(
-            rms_norm(x, shared["ln1"], cfg.rms_eps), shared["attn"], cfg,
-            build.plan, build.mesh, build.rules, positions=positions,
-            return_kv=collect_kv,
-        )
-        x = x + a
-        x = x + swiglu(rms_norm(x, shared["ln2"], cfg.rms_eps),
-                       shared["mlp"]["wi"], shared["mlp"]["wg"], shared["mlp"]["wo"])
-        return _constrain_hidden(x, build), kv
+    def mlp(h, bp):
+        return swiglu(rms_norm(h, bp["ln2"], eps),
+                      bp["mlp"]["wi"], bp["mlp"]["wg"], bp["mlp"]["wo"])
 
-    kvs, new_states = [], []
-    sl = lambda t, a, b: jax.tree.map(lambda v: v[a:b], t)
-    for g in range(n_apps):
-        x, kv = shared_attn(x)
-        kvs.append(kv)
-        span_states = None if states is None else sl(states, g * every, (g + 1) * every)
-        x, st = mamba_span(x, sl(params["blocks"], g * every, (g + 1) * every), span_states)
-        new_states.append(st)
-    if L % every:
-        span_states = None if states is None else sl(states, n_apps * every, L)
-        x, st = mamba_span(x, sl(params["blocks"], n_apps * every, L), span_states)
-        new_states.append(st)
+    def mamba_layer(carry, i):
+        h, st = carry
+        bp = take(params["blocks"], i)
+        out, new = mamba2_block(rms_norm(h, bp["ln"], eps), bp["ssm"], cfg,
+                                None if st is None else take(st, i), length=length)
+        h = h + _scaled(out, r)
+        if "mlp" in bp:
+            h = h + _scaled(mlp(h, bp), r)
+        if collect_state:
+            st = jax.tree.map(lambda a, n: lax.dynamic_update_index_in_dim(
+                a, n.astype(a.dtype), i, 0), st, new)
+        return (constrain(h), st), None
+
+    if not decode:
+        mamba_layer = _maybe_remat(mamba_layer, build)
+    kvs = []
+    for kind, a, b in cfg.hybrid.segments(cfg.n_layers):
+        if kind == "attn":
+            bp = (jax.tree.map(lambda v: v[a], params["attn_blocks"])
+                  if "attn_blocks" in params else params["shared"])
+            out, kv = attention(rms_norm(x, bp["ln1"], eps), bp["attn"], a)
+            x = x + _scaled(out, r)
+            x = constrain(x + _scaled(mlp(x, bp), r))
+            kvs.append(kv)
+        else:
+            (x, states), _ = lax.scan(mamba_layer, (x, states), jnp.arange(a, b),
+                                      unroll=cfg.scan_unroll)
+    return x, kvs, (states if collect_state else None)
+
+
+def forward_hybrid(params, x, build: ModelBuild, *, positions=None,
+                   collect_kv=False, states=None, collect_state=False, length=None):
+    """Hybrid backbone (:func:`hybrid_walk`) over a whole sequence."""
+    cfg = build.cfg
+
+    def attention(h, p, _g):
+        return attention_layer(h, p, cfg, build.plan, build.mesh, build.rules,
+                               positions=positions, return_kv=collect_kv)
+
+    x, kvs, stacked_states = hybrid_walk(params, x, build, attention, states=states,
+                                         collect_state=collect_state, length=length)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     stacked_kv = None
     if collect_kv:
-        ks = jnp.stack([kv[0] for kv in kvs])
-        vs = jnp.stack([kv[1] for kv in kvs])
-        stacked_kv = (ks, vs)
-    stacked_states = (
-        jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *new_states)
-        if collect_state else None
-    )
+        stacked_kv = (jnp.stack([kv[0] for kv in kvs]), jnp.stack([kv[1] for kv in kvs]))
     return x, stacked_kv, stacked_states
 
 
@@ -454,6 +505,13 @@ def make_prefill_fn(cfg: ModelConfig, mesh: Optional[Mesh], remat: str = "full",
     The returned cache is the XDT ephemeral object: sequence-sharded KV (and
     SSM states), ready for a decode pod to pull.  ``pad_to`` grows the KV
     sequence axis to the decode context budget.
+
+    A model with Mamba2 layers also takes ``batch["length"]`` (B,): the real
+    lengths of prompts padded at their ends.  The states are those of the
+    real tokens (padded steps have ``dt = 0``), ``pos`` is the length, and
+    the logits are those of the last real position.  Attention is causal, so
+    the pads change no real position; their KV lies beyond ``pos``, masked,
+    and decode overwrites it.
     """
     build = ModelBuild(cfg, mesh, remat)
 
@@ -470,6 +528,7 @@ def make_prefill_fn(cfg: ModelConfig, mesh: Optional[Mesh], remat: str = "full",
 
     def prefill(params, batch):
         cache: Dict[str, Any] = {}
+        length = batch.get("length")
         if cfg.family in ("dense", "moe", "vlm", "encoder"):
             if cfg.family == "vlm":
                 tok_x = _embed(params, batch["tokens"], build)
@@ -487,32 +546,44 @@ def make_prefill_fn(cfg: ModelConfig, mesh: Optional[Mesh], remat: str = "full",
             cache["k"], cache["v"] = _constrain_cache(_pad_kv(kvs), build)
         elif cfg.family == "ssm":
             x = _embed(params, batch["tokens"], build)
-            S = x.shape[1]
             zero = _zero_states(cfg, x.shape[0], build)
-            x, states = forward_ssm(params, x, build, states=zero, collect_state=True)
+            x, states = forward_ssm(params, x, build, states=zero, collect_state=True,
+                                    length=length)
             cache.update(states)
         else:  # hybrid
             x = _embed(params, batch["tokens"], build)
             zero = _zero_states(cfg, x.shape[0], build)
             x, kvs, states = forward_hybrid(
-                params, x, build, collect_kv=True, states=zero, collect_state=True
+                params, x, build, collect_kv=True, states=zero, collect_state=True,
+                length=length,
             )
             cache["k"], cache["v"] = _constrain_cache(_pad_kv(kvs), build)
             cache["conv"], cache["ssm"] = states["conv"], states["ssm"]
         B = x.shape[0]
         S = x.shape[1]
-        cache["pos"] = jnp.full((B,), S, jnp.int32)
-        logits = _logits(params, x[:, -1:], build)[:, 0]
+        if length is None:
+            cache["pos"] = jnp.full((B,), S, jnp.int32)
+            last = x[:, -1:]
+        else:
+            # a padded prompt: the first token is read at its last real position
+            cache["pos"] = length.astype(jnp.int32)
+            last = jnp.take_along_axis(x, (length - 1)[:, None, None], axis=1)
+        logits = _logits(params, last, build)[:, 0]
         return logits, cache
 
     return prefill
+
+
+def _state_layers(cfg: ModelConfig) -> int:
+    """Layers that hold an SSM state: every layer but attention layers."""
+    return cfg.hybrid.n_mamba(cfg.n_layers) if cfg.hybrid else cfg.n_layers
 
 
 def _zero_states(cfg: ModelConfig, batch: int, build: ModelBuild):
     shapes = ssm_state_shapes(cfg, batch)
     out = {}
     for k, (shape, axes) in shapes.items():
-        full = (cfg.n_layers,) + shape
+        full = (_state_layers(cfg),) + shape
         z = jnp.zeros(full, jnp.float32 if k == "ssm" else cfg.compute_dtype)
         out[k] = _constrain(z, build, ["layers"] + list(axes))
     return out
@@ -560,45 +631,16 @@ def make_decode_fn(cfg: ModelConfig, mesh: Optional[Mesh]):
                                      unroll=cfg.scan_unroll)
             new_cache = dict(cache, pos=pos + 1, **new_states)
         else:  # hybrid
-            h = cfg.hybrid
-            every = h.attn_every
-            n_apps = cfg.n_layers // every
-            shared = params["shared"]
-            sl = lambda t, a, b: jax.tree.map(lambda v: v[a:b], t)
+            def attention(h, p, g):
+                out, k, v = decode_attention_layer(h, p, cfg, cache["k"][g],
+                                                   cache["v"][g], pos)
+                return out, (k, v)
+
             states = {"conv": cache["conv"], "ssm": cache["ssm"]}
-            nk, nv, new_states = [], [], []
-
-            def mamba_span(x, bp_span, st_span):
-                def body(hc, layer):
-                    bp, st = layer
-                    out, new_st = mamba2_block(
-                        rms_norm(hc, bp["ln"], cfg.rms_eps), bp["ssm"], cfg, st
-                    )
-                    return hc + out, new_st
-                return lax.scan(body, x, (bp_span, st_span), unroll=cfg.scan_unroll)
-
-            for g in range(n_apps):
-                hn = rms_norm(x, shared["ln1"], cfg.rms_eps)
-                a, k_g, v_g = decode_attention_layer(
-                    hn, shared["attn"], cfg, cache["k"][g], cache["v"][g], pos
-                )
-                nk.append(k_g)
-                nv.append(v_g)
-                x = x + a
-                x = x + swiglu(rms_norm(x, shared["ln2"], cfg.rms_eps),
-                               shared["mlp"]["wi"], shared["mlp"]["wg"], shared["mlp"]["wo"])
-                x, st = mamba_span(x, sl(params["blocks"], g * every, (g + 1) * every),
-                                   sl(states, g * every, (g + 1) * every))
-                new_states.append(st)
-            if cfg.n_layers % every:
-                x, st = mamba_span(
-                    x, sl(params["blocks"], n_apps * every, cfg.n_layers),
-                    sl(states, n_apps * every, cfg.n_layers))
-                new_states.append(st)
-            merged = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *new_states)
-            new_cache = dict(
-                cache, k=jnp.stack(nk), v=jnp.stack(nv), pos=pos + 1, **merged
-            )
+            x, kvs, merged = hybrid_walk(params, x, build, attention, states=states,
+                                         collect_state=True, decode=True)
+            new_cache = dict(cache, k=jnp.stack([k for k, _ in kvs]),
+                             v=jnp.stack([v for _, v in kvs]), pos=pos + 1, **merged)
 
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         logits = _logits(params, x, build)[:, 0]
@@ -627,13 +669,13 @@ def cache_shapes(cfg: ModelConfig, batch: int, seq_len: int) -> Dict[str, Tuple]
                       jnp.float32 if k == "ssm" else dt)
     else:  # hybrid
         h = cfg.hybrid
-        n_apps = cfg.n_layers // h.attn_every
-        kv = (n_apps, batch, seq_len, h.shared_n_kv_heads, cfg.hd)
+        n_kv = cfg.n_kv_heads if h.attn_layers else h.shared_n_kv_heads
+        kv = (h.n_attn(cfg.n_layers), batch, seq_len, n_kv, cfg.hd)
         axes = ("layers", "batch", "kv_seq", None, None)
         out["k"] = (kv, axes, dt)
         out["v"] = (kv, axes, dt)
         for k, (shape, saxes) in ssm_state_shapes(cfg, batch).items():
-            out[k] = ((cfg.n_layers,) + shape, ("layers",) + tuple(saxes),
+            out[k] = ((_state_layers(cfg),) + shape, ("layers",) + tuple(saxes),
                       jnp.float32 if k == "ssm" else dt)
     out["pos"] = ((batch,), ("batch",), jnp.int32)
     return out

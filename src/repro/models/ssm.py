@@ -161,16 +161,26 @@ def ssd_mix(
     h0: Optional[jax.Array] = None,
     chunk: int = 128,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Mamba-2 SSD in chunked matmul form.  Returns (y, h_last (B,H,hd,ds))."""
+    """Mamba-2 SSD in chunked matmul form.  Returns (y, h_last (B,H,hd,ds)).
+
+    A sequence that is not a whole number of chunks is padded at its end
+    with steps of ``dt = 0``: each leaves the state as it is (decay
+    ``exp(0) = 1``, input ``dt * x = 0``), and their outputs are dropped.
+    Callers pad the same way to mask positions beyond a sequence's length.
+    """
     Bsz, S, H, hd = x_h.shape
     ds = B_ssm.shape[-1]
     f32 = jnp.float32
     if h0 is None:
         h0 = jnp.zeros((Bsz, H, hd, ds), f32)
     chunk = min(chunk, S)
-    if S % chunk:
-        chunk = S
-    n = S // chunk
+    pad = -S % chunk
+    x_in = x_h
+    if pad:
+        x_h, dt, B_ssm, C_ssm = (
+            jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+            for t in (x_h, dt, B_ssm, C_ssm))
+    n = (S + pad) // chunk
 
     A = -jnp.exp(A_log.astype(f32))  # (H,) negative decay rates
 
@@ -198,9 +208,18 @@ def ssd_mix(
         return t.reshape(Bsz, n, chunk, *t.shape[2:]).swapaxes(0, 1)
 
     h_last, ys = lax.scan(per_chunk, h0, (split(x_h), split(dt), split(B_ssm), split(C_ssm)))
-    y = ys.swapaxes(0, 1).reshape(Bsz, S, H, hd).astype(x_h.dtype)
-    y = y + x_h * D[None, None, :, None]
+    y = ys.swapaxes(0, 1).reshape(Bsz, S + pad, H, hd).astype(x_h.dtype)
+    if pad:
+        y = y[:, :S]
+    y = y + x_in * D[None, None, :, None]
     return y, h_last
+
+
+def _last_positions(x: jax.Array, length: jax.Array, n: int) -> jax.Array:
+    """x[b, length[b] - n : length[b]] of each sequence of ``x`` (B, S, C);
+    positions before the first are zeros, as the causal conv's padding."""
+    xp = jnp.pad(x, ((0, 0), (n, 0), (0, 0)))
+    return jax.vmap(lambda row, l: lax.dynamic_slice_in_dim(row, l, n))(xp, length)
 
 
 def mamba2_block(
@@ -208,8 +227,14 @@ def mamba2_block(
     p: Dict[str, jax.Array],
     cfg: ModelConfig,
     state: Optional[Dict[str, jax.Array]] = None,
+    length: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Mamba-2 block.  in_proj emits [z, x, B, C, dt]; conv over (x,B,C)."""
+    """Mamba-2 block.  in_proj emits [z, x, B, C, dt]; conv over (x,B,C).
+
+    ``length`` (B,) (prefill only): each sequence's real length in a
+    padded batch.  Positions from it on get ``dt = 0``, so the final state is
+    the unpadded one, and the conv state is taken from the last real
+    positions."""
     s = cfg.ssm
     d_in = s.expand * cfg.d_model
     H = d_in // s.head_dim
@@ -223,11 +248,17 @@ def mamba2_block(
         xbc_c = jax.nn.silu(conv_out)[:, None]
     else:
         xbc_c = jax.nn.silu(_causal_conv1d(xbc, p["conv_w"], p.get("conv_b")))
-        new_conv = xbc[:, -(s.conv_width - 1):, :] if x.shape[1] >= s.conv_width - 1 else None
+        if length is not None:
+            new_conv = _last_positions(xbc, length, s.conv_width - 1)
+        else:
+            new_conv = xbc[:, -(s.conv_width - 1):, :] if x.shape[1] >= s.conv_width - 1 else None
 
     x_part, B_ssm, C_ssm = jnp.split(xbc_c, [d_in, d_in + ds], axis=-1)
     x_h = x_part.reshape(*x_part.shape[:2], H, s.head_dim)
     dt = jax.nn.softplus(dt_raw + p["dt_bias"])      # (B,S,H)
+    if length is not None and not decode:
+        real = jnp.arange(x.shape[1])[None, :] < length[:, None]
+        dt = jnp.where(real[..., None], dt, 0)
 
     if decode:
         f32 = jnp.float32

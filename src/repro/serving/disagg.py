@@ -77,6 +77,13 @@ def disagg_dag(n_decode_pods: int, cache_bytes: int = NOMINAL_CACHE_BYTES) -> Wo
     )
 
 
+def _handoff_bytes(cache: PyTree) -> Dict[str, int]:
+    """A handed-over cache's bytes: ``state_bytes`` of SSM and conv states,
+    which do not grow with the context, and ``kv_bytes`` of K and V."""
+    return {"state_bytes": sum(cache[k].nbytes for k in ("ssm", "conv") if k in cache),
+            "kv_bytes": sum(cache[k].nbytes for k in ("k", "v") if k in cache)}
+
+
 class DisaggregatedServer:
     """One prefill pod + N decode pods over the XDT substrate."""
 
@@ -152,7 +159,11 @@ class DisaggregatedServer:
     def _prefill_handler(self, ctx, req: Request):
         """Producer stage: compute the cache, mint the ref, invoke decode."""
         # 1. producer computes the ephemeral object
-        with tracing.span("serve.prefill", prompt_tokens=len(req.prompt)):
+        attrs = {}
+        if tracing.enabled():
+            n = len(req.prompt)
+            attrs = {"tokens": n, "padded": self.prefill_pod.padded_length(n)}
+        with tracing.span("serve.prefill", **attrs):
             cache, first_token = self.prefill_pod.prefill_request(req)
         # 2. producer buffers it and mints the reference (data stays put)
         ref: XDTRef = self.transfer.put(cache, n_retrievals=1)
@@ -199,7 +210,8 @@ class DisaggregatedServer:
                 yield self._slot_free_event(pod_idx)
         if wait is not None:
             wait.end()
-        with tracing.span("serve.insert", pod=pod_idx, slot=slot):
+        attrs = _handoff_bytes(pulled) if tracing.enabled() else {}
+        with tracing.span("serve.insert", pod=pod_idx, slot=slot, **attrs):
             pod.admit(req, pulled, first_token, slot)
         self.pod_of_request[req.request_id] = pod_idx
         self.instance_of_request[req.request_id] = ctx.instance.instance_id

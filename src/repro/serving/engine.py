@@ -109,6 +109,11 @@ class ServingEngine:
         self.max_len = max_len
         #: this pod's index among its server's, in traces
         self.pod = pod
+        #: prompts are padded to a whole number of these tokens: the SSD chunk
+        #: of a model with Mamba2 layers, so that prefill compiles one program
+        #: per block count and never runs a partial chunk; 0 (every other
+        #: model): each prompt is served at its own length
+        self.prompt_block = cfg.ssm.chunk if cfg.ssm is not None and cfg.ssm.version == 2 else 0
         self.prefill, self.decode = _step_fns(cfg, mesh, max_len)
         self.cache = empty_cache(cfg, max_batch, max_len)
         self.slots: List[Optional[Request]] = [None] * max_batch
@@ -127,11 +132,25 @@ class ServingEngine:
         self.queue.append(req)
         return req.request_id
 
+    def padded_length(self, n: int) -> int:
+        """The length a prompt of ``n`` tokens is prefilled at."""
+        if not self.prompt_block:
+            return n
+        return min(-(-n // self.prompt_block) * self.prompt_block, self.max_len)
+
     def prefill_request(self, req: Request) -> Tuple[PyTree, int]:
         """Run prefill for one request; returns (cache, first_token)."""
-        logits, cache = self.prefill(
-            self.params, {"tokens": jnp.asarray(req.prompt)[None]}
-        )
+        n, padded = len(req.prompt), self.padded_length(len(req.prompt))
+        if tracing.enabled():
+            tracing.count("prefill.tokens", n)
+            tracing.count("prefill.pad_tokens", padded - n)
+        if self.prompt_block:
+            tokens = np.zeros((1, padded), np.int32)
+            tokens[0, :n] = req.prompt
+            batch = {"tokens": jnp.asarray(tokens), "length": jnp.asarray([n], jnp.int32)}
+        else:
+            batch = {"tokens": jnp.asarray(req.prompt)[None]}
+        logits, cache = self.prefill(self.params, batch)
         return cache, int(host_read(jnp.argmax(logits[0])))
 
     def admit(self, req: Request, cache: PyTree, first_token: int, slot: int) -> None:

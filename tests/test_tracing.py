@@ -215,8 +215,27 @@ def test_each_round_reads_every_pods_tokens_once_after_their_dispatch(traced):
                    if r.start <= s.start <= r.end) for r in rounds) == 2
     # and each prefill reads its first token once
     prefills = [s for s in _spans(recs) if s.name == "serve.prefill"]
-    counts = [c for c in recs if isinstance(c, tracing.Count)]
+    counts = [c for c in recs if isinstance(c, tracing.Count) and c.name == "host.syncs"]
     assert sum(1 for c in counts for p in prefills if p.start <= c.t <= p.end) == 3
+
+
+def test_prefill_counts_and_handoff_bytes(traced, model):
+    """Each prefill counts its prompt's tokens and the pads after them (none
+    for a dense model, which is prefilled at the prompt's own length), and
+    ``serve.prefill`` and ``serve.insert`` carry the lengths and the
+    handed-over bytes (a dense model's cache is all K and V)."""
+    cfg, _ = model
+    recs = traced["serve"]
+    counts = collections.defaultdict(list)
+    for c in recs:
+        if isinstance(c, tracing.Count):
+            counts[c.name].append(c.n)
+    assert counts["prefill.tokens"] == [5, 5, 5] and counts["prefill.pad_tokens"] == [0, 0, 0]
+    prefills = [s for s in _spans(recs) if s.name == "serve.prefill"]
+    assert [(s.attrs["tokens"], s.attrs["padded"]) for s in prefills] == [(5, 5)] * 3
+    kv = 2 * cfg.n_layers * 32 * cfg.n_kv_heads * cfg.hd * 2     # K and V, max_len 32, bf16
+    inserts = [s for s in _spans(recs) if s.name == "serve.insert"]
+    assert [(s.attrs["state_bytes"], s.attrs["kv_bytes"]) for s in inserts] == [(0, kv)] * 3
 
 
 def test_profiler_trace_holds_each_span_nested_as_the_records(traced):
