@@ -8,7 +8,6 @@ baseline is the paper-faithful implementation):
   zero1          ZeRO-1 optimizer-state sharding over the data/pod axes
   seq_shard      Megatron sequence parallelism for inter-block activations
   moe_a2a        all-to-all expert dispatch (the paper's scatter/gather)
-  scatter_kv     serve_step KV update via scatter instead of one-hot rewrite
 
 Usage (needs the 512-device flag, so run as a module, NOT under pytest):
 
@@ -58,8 +57,6 @@ def apply_variant(cfg, variant: str):
             assert cfg.moe is not None, "moe_a2a needs an MoE arch"
             cfg = dataclasses.replace(
                 cfg, moe=dataclasses.replace(cfg.moe, dispatch="a2a"))
-        elif knob == "scatter_kv":
-            cfg = dataclasses.replace(cfg, decode_scatter_update=True)
         elif knob == "fsdp":
             cfg = dataclasses.replace(cfg, fsdp_params=True)
         elif knob == "combo":

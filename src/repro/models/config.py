@@ -125,9 +125,6 @@ class ModelConfig:
     seq_shard_acts: bool = False        # Megatron-style sequence parallelism:
                                         # inter-block activations sharded over
                                         # the model axis (AG/RS replace psum)
-    decode_scatter_update: bool = False # serve_step KV update via scatter
-                                        # (O(B) bytes) instead of the one-hot
-                                        # full-cache rewrite (O(B*T) x3)
     fsdp_params: bool = False           # shard params' d_model dim over the
                                         # data axis (ZeRO-3/FSDP via GSPMD):
                                         # per-layer weight all-gathers replace

@@ -276,9 +276,11 @@ def decode_attention_layer(
     cache_v: jax.Array,
     seq_positions: jax.Array,     # (B,) current length of each sequence
 ):
-    """One-token decode: update cache at seq_positions, attend over prefix.
+    """One-token decode: attend over the prefix and the new token, which
+    stands at ``seq_positions`` in the cache's place.  The cache is read,
+    not written: the new rows are returned for :func:`write_cache_rows`.
 
-    Returns (out (B,1,D), new_cache_k, new_cache_v).
+    Returns (out (B,1,D), k_row (B,KV,hd), v_row (B,KV,hd)).
     """
     B, _, D = x.shape
     T = cache_k.shape[1]
@@ -287,36 +289,89 @@ def decode_attention_layer(
         cos, sin = rope_angles(seq_positions[:, None], cfg.hd, cfg.rope_theta)  # (B,1,half)
         q = apply_rope(q, cos, sin)
         k_new = apply_rope(k_new, cos, sin)
+    k_new = k_new.astype(cache_k.dtype)
+    v_new = v_new.astype(cache_v.dtype)
 
-    if cfg.decode_scatter_update:
-        # §Perf hillclimb: a scatter touches only the updated row — with the
-        # cache donated, XLA aliases input->output and the update's HBM
-        # traffic is O(B*KV*hd), not O(B*T*KV*hd) x3.  Decode then streams
-        # the cache ONCE (the attention read): its memory-roofline minimum.
-        b_idx = jnp.arange(B)
-        cache_k = cache_k.at[b_idx, seq_positions].set(
-            k_new[:, 0].astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[b_idx, seq_positions].set(
-            v_new[:, 0].astype(cache_v.dtype), mode="drop")
-    else:
-        # baseline: one-hot masked rewrite (full-cache read+write; the op
-        # stays trivially local under any cache sharding)
-        onehot = jax.nn.one_hot(seq_positions, T, dtype=cache_k.dtype)  # (B, T)
-        sel = onehot[:, :, None, None]
-        cache_k = cache_k * (1 - sel) + sel * k_new
-        cache_v = cache_v * (1 - sel) + sel * v_new
+    # the new row in its position, selected inside the attention read
+    here = (jnp.arange(T)[None, :] == seq_positions[:, None])[:, :, None, None]
+    ck = jnp.where(here, k_new, cache_k)
+    cv = jnp.where(here, v_new, cache_v)
 
     KV = cache_k.shape[2]
     G = cfg.n_heads // KV
     qg = q.reshape(B, KV, G, cfg.hd)  # Sq == 1 squeezed
-    scores = jnp.einsum("bkgh,btkh->bkgt", qg, cache_k).astype(jnp.float32)
+    scores = jnp.einsum("bkgh,btkh->bkgt", qg, ck).astype(jnp.float32)
     scores *= cfg.hd ** -0.5 if cfg.attn_scale is None else cfg.attn_scale
     valid = jnp.arange(T)[None, :] <= seq_positions[:, None]  # (B, T)
     scores = jnp.where(valid[:, None, None], scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bkgt,btkh->bkgh", w, cache_v).reshape(B, 1, cfg.n_heads * cfg.hd)
+    out = jnp.einsum("bkgt,btkh->bkgh", w, cv).reshape(B, 1, cfg.n_heads * cfg.hd)
     out = jnp.einsum("bsk,kd->bsd", out, p["wo"].reshape(cfg.n_heads * cfg.hd, D))
-    return out, cache_k, cache_v
+    return out, k_new[:, 0], v_new[:, 0]
+
+
+#: positions written together by :func:`write_cache_rows`: the TPU's lane
+#: tile, along which a (…, T, KV, hd) cache is laid out
+_ROW_BLOCK = 128
+
+#: logical axes of a (L, B, T, KV, hd) KV cache, as ``cache_shapes`` gives them
+_CACHE_AXES = ("layers", "batch", "kv_seq", None, None)
+
+
+def write_cache_rows(cache: jax.Array, rows: jax.Array, positions: jax.Array,
+                     rules: Optional[ShardingRules] = None):
+    """Write each slot's new row of every layer into the cache in place:
+    ``cache`` (L, B, T, KV, hd) gets ``rows`` (L, B, KV, hd) at
+    ``[:, b, positions[b]]``; a position past the cache (a slot left empty)
+    writes nothing.
+
+    Each slot is one dynamic-update-slice of the block of ``_ROW_BLOCK``
+    positions around its row, the rest of the block written back as read:
+    the TPU lays the cache out with positions along its lanes, and a write
+    one position wide (a scatter, or a dynamic-update-slice per row) has the
+    compiler copy the whole cache into another layout on the way into the
+    step and back out of it.  With the cache donated the step reads and
+    writes B blocks of L x W rows, not the cache.  A cache whose length is
+    not a multiple of ``_ROW_BLOCK`` is written a whole (L, 1, T) slab per
+    slot.
+
+    With ``rules`` (a mesh) the cache is laid out as they resolve
+    ``_CACHE_AXES``, positions split over the mesh's ``kv_seq`` axes, and
+    each device writes the rows that fall in its own shard: a write at a
+    position known only at run time along a split axis would otherwise
+    gather the whole cache onto every device."""
+    if rules is None:
+        return _write_rows_local(cache, rows, positions)
+    spec = rules.resolve(list(_CACHE_AXES), cache.shape)
+    seq_axes = spec[2]
+
+    def local(c, r, p):
+        if seq_axes is not None:
+            p = p - lax.axis_index(seq_axes) * c.shape[2]
+        return _write_rows_local(c, r, p)
+
+    return shard_map(
+        local,
+        mesh=rules.mesh,
+        in_specs=(spec, P(spec[0], spec[1], spec[3], spec[4]), P(spec[1])),
+        out_specs=spec,
+        check_vma=False,
+    )(cache, rows, positions)
+
+
+def _write_rows_local(cache: jax.Array, rows: jax.Array, positions: jax.Array):
+    """:func:`write_cache_rows` on one device; a position outside
+    ``[0, T)`` writes nothing."""
+    L, B, T = cache.shape[:3]
+    W = _ROW_BLOCK if T % _ROW_BLOCK == 0 else T
+    offsets = jnp.arange(W)
+    for b in range(B):
+        start = jnp.clip(positions[b], 0, T - 1) // W * W
+        block = lax.dynamic_slice(cache, (0, b, start, 0, 0), (L, 1, W) + cache.shape[3:])
+        hit = (start + offsets == positions[b])[None, None, :, None, None]
+        block = jnp.where(hit, rows[:, b, None, None].astype(cache.dtype), block)
+        cache = lax.dynamic_update_slice(cache, block, (0, b, start, 0, 0))
+    return cache
 
 
 # ---------------------------------------------------------------------------

@@ -45,6 +45,7 @@ from .layers import (
     plan_attention,
     rms_norm,
     swiglu,
+    write_cache_rows,
 )
 from .moe import moe_layer, moe_param_shapes
 from .ssm import mamba1_block, mamba2_block, ssm_param_shapes, ssm_state_shapes
@@ -592,7 +593,11 @@ def _zero_states(cfg: ModelConfig, batch: int, build: ModelBuild):
 def make_decode_fn(cfg: ModelConfig, mesh: Optional[Mesh]):
     """Returns decode(params, cache, tokens (B,1)) -> (logits (B,V), cache).
 
-    This is ``serve_step``: one new token against the resident cache.
+    This is ``serve_step``: one new token against the resident cache.  The
+    layers read the KV cache and return each slot's new row;
+    :func:`write_cache_rows` writes them in after the last layer, so that a
+    caller that donates the cache updates it in place (under a mesh, each
+    device in its own shard of the cache).
     """
     build = ModelBuild(cfg, mesh, remat="none")
 
@@ -605,18 +610,20 @@ def make_decode_fn(cfg: ModelConfig, mesh: Optional[Mesh]):
                 h = carry
                 bp, ck, cv = layer
                 hn = rms_norm(h, bp["ln1"], cfg.rms_eps)
-                a, nk, nv = decode_attention_layer(hn, bp["attn"], cfg, ck, cv, pos)
+                a, k_row, v_row = decode_attention_layer(hn, bp["attn"], cfg, ck, cv, pos)
                 h = h + a
                 hn = rms_norm(h, bp["ln2"], cfg.rms_eps)
                 if cfg.family == "moe":
                     m, _ = moe_layer(hn, bp["moe"], cfg, build.mesh)
                 else:
                     m = swiglu(hn, bp["mlp"]["wi"], bp["mlp"]["wg"], bp["mlp"]["wo"])
-                return h + m, (nk, nv)
+                return h + m, (k_row, v_row)
 
-            x, (nk, nv) = lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]),
-                                   unroll=cfg.scan_unroll)
-            new_cache = dict(cache, k=nk, v=nv, pos=pos + 1)
+            x, (k_rows, v_rows) = lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]),
+                                           unroll=cfg.scan_unroll)
+            new_cache = dict(cache, k=write_cache_rows(cache["k"], k_rows, pos, build.rules),
+                             v=write_cache_rows(cache["v"], v_rows, pos, build.rules),
+                             pos=pos + 1)
         elif cfg.family == "ssm":
             def body(carry, layer):
                 h = carry
@@ -637,10 +644,13 @@ def make_decode_fn(cfg: ModelConfig, mesh: Optional[Mesh]):
                 return out, (k, v)
 
             states = {"conv": cache["conv"], "ssm": cache["ssm"]}
-            x, kvs, merged = hybrid_walk(params, x, build, attention, states=states,
-                                         collect_state=True, decode=True)
-            new_cache = dict(cache, k=jnp.stack([k for k, _ in kvs]),
-                             v=jnp.stack([v for _, v in kvs]), pos=pos + 1, **merged)
+            x, rows, merged = hybrid_walk(params, x, build, attention, states=states,
+                                          collect_state=True, decode=True)
+            k_rows = jnp.stack([k for k, _ in rows])
+            v_rows = jnp.stack([v for _, v in rows])
+            new_cache = dict(cache, k=write_cache_rows(cache["k"], k_rows, pos, build.rules),
+                             v=write_cache_rows(cache["v"], v_rows, pos, build.rules),
+                             pos=pos + 1, **merged)
 
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         logits = _logits(params, x, build)[:, 0]

@@ -72,21 +72,28 @@ def insert_cache(batch_cache: PyTree, single_cache: PyTree, slot: int) -> PyTree
 
 @functools.lru_cache(maxsize=None)
 def _step_fns(cfg: ModelConfig, mesh, max_len: int):
-    """Jitted (prefill, decode) shared by every engine with the same config,
+    """(jitted prefill, decode) shared by every engine with the same config,
     mesh and context budget: the pods of one server compile each shape once.
-    The decode program also takes each slot's greedy token, so that one
+    The decode step also takes each slot's greedy token, so that one
     dispatch steps a pod: ``decode(params, cache, tokens (B, 1)) -> (next
-    tokens (B, 1) int32, cache)``."""
+    tokens (B, 1) int32, cache)``.  An engine runs it through
+    :func:`_donating`."""
     step = make_decode_fn(cfg, mesh)
 
     def decode(params, cache, tokens):
         logits, cache = step(params, cache, tokens)
         return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32), cache
 
-    return (
-        jax.jit(make_prefill_fn(cfg, mesh, remat="none", pad_to=max_len)),
-        jax.jit(decode),
-    )
+    return jax.jit(make_prefill_fn(cfg, mesh, remat="none", pad_to=max_len)), decode
+
+
+@functools.lru_cache(maxsize=None)
+def _donating(decode):
+    """``decode`` jitted with its cache donated: the step writes one row per
+    slot into the buffers it was given, and the caller's cache is deleted.
+    The engine rebinds its cache to the result at once and nothing else
+    holds it."""
+    return jax.jit(decode, donate_argnums=(1,))
 
 
 class ServingEngine:
@@ -114,7 +121,8 @@ class ServingEngine:
         #: per block count and never runs a partial chunk; 0 (every other
         #: model): each prompt is served at its own length
         self.prompt_block = cfg.ssm.chunk if cfg.ssm is not None and cfg.ssm.version == 2 else 0
-        self.prefill, self.decode = _step_fns(cfg, mesh, max_len)
+        self.prefill, decode = _step_fns(cfg, mesh, max_len)
+        self.decode = _donating(decode)
         self.cache = empty_cache(cfg, max_batch, max_len)
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.last_tokens = jnp.zeros((max_batch, 1), jnp.int32)

@@ -14,6 +14,7 @@ os.environ["XLA_FLAGS"] = (
 )
 
 import json
+import re
 import tempfile
 import traceback
 
@@ -29,7 +30,8 @@ from repro.core.patterns import build_pattern_fn
 from repro.data import ShardedLoader
 from repro.distributed.sharding import ShardingRules
 from repro.launch.mesh import make_host_mesh
-from repro.models import init_params, make_loss_fn, param_shapes
+from repro.models import (cache_shapes, init_params, make_decode_fn, make_loss_fn,
+                          param_shapes)
 from repro.models.moe import moe_dense_oracle
 from repro.optim import OptConfig, adamw_init
 from repro.optim.compression import compressed_psum
@@ -110,6 +112,64 @@ def check_seq_parallel_attention():
         "single": loss_single,
         "mesh": loss_mesh,
     }
+
+
+def check_sharded_decode_writes_locally():
+    """A decode step with the KV cache's positions split over a 4-way model
+    axis writes each slot's rows on the device that holds them: nothing but
+    the written rows changes, the rows and logits match the unsharded step,
+    and no all-gather moves as much as one device's share of a K or V leaf.
+    Slots sit at a block edge, at a shard's first position, at the cache's
+    last one and past it."""
+    out = {}
+    B, T = 4, 1024
+    positions = np.asarray([127, 256, T - 1, T], np.int32)
+    mesh = make_host_mesh(data=2, model=4)
+    rules = ShardingRules(mesh)
+    is_spec = lambda x: isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
+    for arch in ("smollm_360m", "granite-4.0-h-micro"):
+        cfg = smoke_config(arch)
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        rng = np.random.default_rng(0)
+        shapes = cache_shapes(cfg, B, T)
+        cache = {k: jnp.asarray(rng.standard_normal(shape), dt)
+                 for k, (shape, _axes, dt) in shapes.items()}
+        cache["pos"] = jnp.asarray(positions)
+        tokens = jnp.asarray(rng.integers(1, cfg.vocab, (B, 1)), jnp.int32)
+        logits, want = jax.jit(make_decode_fn(cfg, None))(params, cache, tokens)
+
+        params_sh = jax.tree.map(
+            lambda spec, v: jax.device_put(v, rules.named(list(spec[1]), v.shape)),
+            param_shapes(cfg), params, is_leaf=is_spec)
+        cache_sh = {k: jax.device_put(v, rules.named(list(shapes[k][1]), v.shape))
+                    for k, v in cache.items()}
+        tokens_sh = jax.device_put(tokens, rules.named(["batch", None], tokens.shape))
+        step = jax.jit(make_decode_fn(cfg, mesh))
+        with mesh:
+            hlo = step.lower(params_sh, cache_sh, tokens_sh).compile().as_text()
+            logits_sh, got = step(params_sh, cache_sh, tokens_sh)
+
+        written = np.zeros(cache["k"].shape[:3], bool)
+        for b, p in enumerate(positions):
+            if p < T:
+                written[:, b, p] = True
+        kept = rows = True
+        for key in ("k", "v"):
+            a, g, w = (np.asarray(x[key], np.float32) for x in (cache, got, want))
+            kept &= bool(np.array_equal(g[~written], a[~written]))
+            rows &= bool(np.allclose(g[written], w[written], rtol=0.05, atol=0.05))
+        gathered = sum(
+            int(np.prod([int(d) for d in dims.split(",") if d]))
+            for line in hlo.splitlines() if re.search(r" all-gather(-start)?\(", line)
+            for dims in re.findall(r"\w+\[([\d,]*)\]", line.split(" all-gather")[0]))
+        shard = cache["k"].size // len(mesh.devices.flat)
+        gap = float(np.max(np.abs(np.asarray(logits_sh, np.float32)
+                                  - np.asarray(logits, np.float32))))
+        out[arch] = {"kept": kept, "rows": rows, "gathered_elems": gathered,
+                     "cache_leaf_elems_per_device": shard, "max_logit_gap": gap}
+    out["ok"] = all(r["kept"] and r["rows"] and r["gathered_elems"] < r["cache_leaf_elems_per_device"]
+                    and r["max_logit_gap"] < 0.15 for r in out.values())
+    return out
 
 
 def check_moe_ep_matches_oracle():
@@ -256,6 +316,7 @@ CHECKS = {
     "patterns": check_patterns,
     "sharded_train": check_sharded_train_matches_single,
     "seq_parallel_attention": check_seq_parallel_attention,
+    "sharded_decode": check_sharded_decode_writes_locally,
     "moe_ep_oracle": check_moe_ep_matches_oracle,
     "compressed_psum": check_compressed_psum,
     "elastic_checkpoint": check_elastic_checkpoint,

@@ -120,7 +120,7 @@ def test_decode_attention_ragged_lengths():
 def test_decode_matches_model_decode_layer():
     """Kernel == decode_attention_layer's math for the same KV/positions."""
     from repro.models.config import ModelConfig
-    from repro.models.layers import decode_attention_layer
+    from repro.models.layers import decode_attention_layer, write_cache_rows
 
     cfg = ModelConfig(name="t", family="dense", n_layers=1, d_model=64,
                       n_heads=4, n_kv_heads=2, d_ff=128, vocab=64, head_dim=16)
@@ -136,7 +136,9 @@ def test_decode_matches_model_decode_layer():
     cache_k = _rand(ks[5], (B, T, 2, 16), jnp.float32)
     cache_v = _rand(ks[5], (B, T, 2, 16), jnp.float32)
     pos = jnp.asarray([3, 17])
-    out_layer, nk, nv = decode_attention_layer(x, p, cfg, cache_k, cache_v, pos)
+    out_layer, k_row, v_row = decode_attention_layer(x, p, cfg, cache_k, cache_v, pos)
+    nk = write_cache_rows(cache_k[None], k_row[None], pos)[0]
+    nv = write_cache_rows(cache_v[None], v_row[None], pos)[0]
 
     # reproduce with the kernel on the updated cache
     from repro.models.layers import _project_qkv, apply_rope, rope_angles

@@ -22,6 +22,13 @@ def test_seq_parallel_attention_plan(multidevice_results):
     _assert_ok(multidevice_results, "seq_parallel_attention")
 
 
+def test_sharded_decode_writes_its_rows_locally(multidevice_results):
+    """With the KV cache's positions split over the model axis, a decode
+    step changes only its rows, matches the unsharded step, and gathers no
+    cache leaf."""
+    _assert_ok(multidevice_results, "sharded_decode")
+
+
 def test_moe_expert_parallel_matches_dense_oracle(multidevice_results):
     """EP-sharded MoE dispatch == dense all-experts oracle (high capacity)."""
     _assert_ok(multidevice_results, "moe_ep_oracle")

@@ -1,4 +1,6 @@
 """Serving: continuous batching engine + disaggregated XDT handoff."""
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -159,6 +161,44 @@ def test_engine_step_reads_its_tokens_once(setup, tmp_path):
     tracing.clear()
     assert live == [3, 2, 1, 1, 0]
     assert reads == [1, 1, 1, 1, 0]
+
+
+def _compiled_decode(eng):
+    return eng.decode.lower(eng.params, eng.cache, eng.last_tokens).compile()
+
+
+@pytest.mark.parametrize("arch", ["smollm_360m", "granite-4.0-h-micro"])
+def test_engine_decode_aliases_its_whole_cache(arch):
+    """The engine's decode program takes its cache donated: every byte of
+    the cache it is given is the cache it returns."""
+    cfg = smoke_config(arch)
+    eng = ServingEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)), max_batch=2, max_len=32)
+    nbytes = sum(x.nbytes for x in jax.tree.leaves(eng.cache))
+    assert _compiled_decode(eng).memory_analysis().alias_size_in_bytes == nbytes
+
+
+def test_engine_decode_copies_no_cache_leaf(setup):
+    """No op of the optimised decode program copies a whole cache leaf."""
+    cfg, params = setup
+    eng = ServingEngine(cfg, params, max_batch=2, max_len=32)
+    hlo_types = {jnp.dtype(jnp.bfloat16): "bf16", jnp.dtype(jnp.float32): "f32"}
+    leaves = {f"{hlo_types[x.dtype]}[{','.join(map(str, x.shape))}]"
+              for x in jax.tree.leaves(eng.cache) if x.ndim > 1}
+    copies = re.findall(r"= (\w+\[[\d,]*\])\{[^}]*\} copy\(", _compiled_decode(eng).as_text())
+    assert leaves and not leaves & set(copies)
+
+
+def test_engine_step_deletes_the_previous_cache(setup):
+    """A step hands its cache to the decode program, which deletes it: the
+    engine holds only the cache the step returned."""
+    cfg, params = setup
+    eng = ServingEngine(cfg, params, max_batch=2, max_len=32)
+    eng.submit(np.arange(1, 6), max_new_tokens=4)
+    eng.step()                          # admits, then steps
+    previous = jax.tree.leaves(eng.cache)
+    eng.step()
+    assert all(x.is_deleted() for x in previous)
+    assert not any(x.is_deleted() for x in jax.tree.leaves(eng.cache))
 
 
 def test_disagg_placement_spreads_load(setup):

@@ -5,10 +5,12 @@ never asks Mosaic whether it accepts their blocking and VMEM use.  These
 tests hand the kernels, at the widths of the configurations they serve, to
 the TPU compiler for one chip of a ``v5e:2x2`` topology; nothing runs.
 Each asserts that the kernel reached the compiled program as a Mosaic
-custom call.  The topology is described inside a fixture (never at import):
+custom call.  The serving engine's decode program is compiled the same way,
+to see that the TPU compiler updates its cache in place.  The topology is described inside a fixture (never at import):
 only the worker that runs this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -44,22 +46,27 @@ def one_chip(topo):
 
 
 @pytest.fixture
-def compile_for_chip(one_chip):
-    """Compile ``fn`` for one described chip with the persistent cache off
-    (an entry written here could not be read back without a chip)."""
+def no_compile_cache():
+    """The persistent compile cache off: an entry written here could not be
+    read back without a chip."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
 
+
+@pytest.fixture
+def compile_for_chip(one_chip, no_compile_cache):
+    """Compile ``fn`` for one described chip."""
     def compile_(fn, *shapes):
         args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
         return jax.jit(fn).lower(*args).compile()
 
-    yield compile_
-    jax.config.update("jax_enable_compilation_cache", prev)
-    cc.reset_cache()
+    return compile_
 
 
 @pytest.mark.parametrize("arch", sorted(ATTN_WIDTHS))
@@ -107,3 +114,33 @@ def test_xdt_pull_compiles(variant, D, compile_for_chip):
             lambda s, sc: xdt_pull(s, sc, out_dtype=BF16),
             ((N, D), jnp.int8), ((N,), F32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_engine_decode_updates_smollm_cache_in_place(one_chip, no_compile_cache):
+    """The engine's decode program for SmolLM-360M at a pod of the serving
+    benchmark (16 slots over 2048 positions) aliases its whole cache and
+    copies no cache leaf: a one-position write into the cache's lane layout
+    (a scatter, or a dynamic-update-slice of one row) makes the TPU
+    compiler copy each leaf whole on the way into the step and out of it."""
+    from repro.configs import get_config
+    from repro.models import abstract_params, cache_shapes
+    from repro.serving.engine import _donating, _step_fns
+
+    cfg, B, T = get_config("smollm_360m"), 16, 2048
+    on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(on_chip, abstract_params(cfg, None))
+    cache = {k: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+             for k, (shape, _axes, dt) in cache_shapes(cfg, B, T).items()}
+    tokens = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    compiled = _donating(_step_fns(cfg, None, T)[1]).lower(params, cache, tokens).compile()
+
+    # the chip pads the (B,) positions to a tile, so the aliased bytes may
+    # exceed the cache's own
+    nbytes = sum(x.size * x.dtype.itemsize for x in cache.values())
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+    hlo_types = {jnp.dtype(BF16): "bf16", jnp.dtype(F32): "f32"}
+    leaves = {f"{hlo_types[jnp.dtype(x.dtype)]}[{','.join(map(str, x.shape))}]"
+              for x in cache.values() if x.ndim > 1}
+    copies = set(re.findall(r"= (\w+\[[\d,]*\])\{[^}]*\} copy(?:-start)?\(",
+                            compiled.as_text()))
+    assert leaves and not leaves & copies
