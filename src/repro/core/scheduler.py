@@ -7,9 +7,8 @@ cold starts buffer the invocation until a new instance is up.
 
 XDT's core compatibility claim is that the control plane is **unchanged** —
 placement decisions happen exactly here, before any bulk data moves, and the
-data plane then pulls producer->chosen-consumer directly.  The serving engine
-(`repro.serving`) uses this scheduler to pick decode slices; the workflow
-engine uses it to pick function instances.
+data plane then pulls producer->chosen-consumer directly.  The workflow
+engine uses this scheduler to pick function instances.
 
 All time-dependent decisions (keep-alive reaping, cold-start gates) read the
 injected clock (:mod:`repro.core.clock`): real time by default, a

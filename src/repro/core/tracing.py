@@ -37,7 +37,8 @@ Names are stable; they are what the readers of the records look up:
 ``serve.slot_wait`` a handoff parked behind a full decode batch, until
                     its admit (detached: no parent)
 ``serve.round``     one ``DisaggregatedServer.step``
-``serve.release``   a round's completion events and the simulator run
+``serve.release``   the admits of parked handoffs into the slots the
+                    round freed
 ``serve.decode``    one pod's decode dispatch
 ``host.sync``       one device-to-host read; inside a round, the round's
                     one read of every pod's tokens

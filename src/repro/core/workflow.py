@@ -956,9 +956,9 @@ class _InvocationTask(AsyncResult):
                     h._waiters.append(fan)
             return False
         if isinstance(yielded, Event):
-            # raw simulator event: lets handlers wait on external completion
-            # signals (e.g. the disaggregated server bridging real decode
-            # completion into virtual time)
+            # raw simulator event: lets handlers wait on a signal that is
+            # not an invocation (e.g. the streaming credit gate of
+            # ``core/dag.py``, which parks a producer until a chunk is freed)
             if yielded.fired:
                 self.send = yielded.value
                 return True

@@ -213,6 +213,33 @@ def test_disagg_placement_spreads_load(setup):
     assert pods == {0, 1}
 
 
+def test_disagg_placement_and_admission_order(setup):
+    """Each handoff goes to the pod with the fewest live and parked requests
+    (ties to the lowest index), and one that finds its pod full waits there,
+    in order, for a slot that pod frees: pod 1 is free from round 4, yet the
+    last request waits for pod 0 until round 8."""
+    cfg, params = setup
+    srv = DisaggregatedServer(cfg, params, n_decode_pods=2, max_batch=2,
+                              max_len=32, backend="xdt")
+    admitted, rounds = {}, 0
+
+    def seen():
+        for rid, pod in srv.pod_of_request.items():
+            admitted.setdefault(rid, (rounds, pod))
+
+    rids = []
+    for i, n in enumerate([9, 3, 7, 5, 4, 6, 3]):
+        rids.append(srv.submit(np.arange(1, 5 + i) + i, max_new_tokens=n))
+        seen()
+    while any(s is not None for pod in srv.decode_pods for s in pod.slots):
+        srv.step()
+        rounds += 1
+        seen()
+    assert [admitted[r] for r in rids] == \
+        [(0, 0), (0, 1), (0, 0), (0, 1), (6, 0), (2, 1), (8, 0)]
+    assert srv.handoffs == 7
+
+
 def test_disagg_handoff_report(setup):
     cfg, params = setup
     srv = DisaggregatedServer(cfg, params, n_decode_pods=1, max_batch=2,
@@ -245,8 +272,8 @@ def _failing_prefill(*_args, **_kw):
 
 
 def test_disagg_surfaces_a_failed_prefill(setup, monkeypatch):
-    """A handler error ends the handoff's workflow request as failed; the
-    server raises it instead of reporting a request that never ran."""
+    """An error in prefill raises from ``submit`` itself, and no handoff is
+    counted for the request that never ran."""
     cfg, params = setup
     srv = DisaggregatedServer(cfg, params, n_decode_pods=1, max_batch=2,
                               max_len=32, backend="xdt")

@@ -21,7 +21,7 @@ from repro.serving import DisaggregatedServer
 WF_SPANS = {"wf.request", "wf.invoke", "wf.steer", "wf.handler", "xfer.put", "xfer.get"}
 SERVE_SPANS = {"serve.submit", "serve.prefill", "serve.insert", "serve.slot_wait",
                "serve.round", "serve.release", "serve.decode", "host.sync",
-               "wf.request", "wf.steer", "wf.handler", "xfer.put", "xfer.get"}
+               "xfer.put", "xfer.get"}
 
 
 @pytest.fixture(autouse=True)
@@ -173,16 +173,16 @@ def test_serving_spans_have_their_parents_and_requests(traced):
     parents = _parent_names(recs)
     assert parents["serve.submit"] == parents["serve.round"] == {None}
     assert parents["serve.slot_wait"] == {None}
-    assert parents["wf.request"] == {"serve.submit"}
-    assert parents["serve.prefill"] == parents["serve.insert"] == {"wf.handler"}
+    assert parents["serve.prefill"] == parents["xfer.put"] == parents["xfer.get"] \
+        == {"serve.submit"}
+    assert parents["serve.insert"] == {"serve.submit", "serve.release"}
     assert parents["serve.decode"] == parents["serve.release"] == {"serve.round"}
     assert parents["host.sync"] == {"serve.prefill", "serve.round"}
-    assert parents["wf.handler"] == {"serve.submit", "serve.release"}
     rids, _ = traced["served"]
     for rid in rids:
         mine = collections.Counter(s.name for s in recs if s.request == rid)
-        for name in ("serve.submit", "wf.request", "serve.prefill", "xfer.put",
-                     "xfer.get", "serve.insert"):
+        for name in ("serve.submit", "serve.prefill", "xfer.put", "xfer.get",
+                     "serve.insert"):
             assert mine[name] == 1, (rid, name, mine)
     (wait,) = [s for s in recs if s.name == "serve.slot_wait"]
     assert wait.request == rids[2]
@@ -190,9 +190,7 @@ def test_serving_spans_have_their_parents_and_requests(traced):
     assert wait.start < wait.end <= insert.start
     # the parked handoff is admitted inside a round's release
     by_id = {s.id: s for s in recs}
-    assert {by_id[s.parent].name for s in recs
-            if s.name == "wf.handler" and s.request == rids[2]} \
-        == {"serve.submit", "serve.release"}
+    assert by_id[insert.parent].name == "serve.release"
     # round spans belong to no request
     assert {s.request for s in recs if s.name in ("serve.round", "serve.decode")} == {None}
 
